@@ -25,6 +25,8 @@ def main() -> None:
                     help="stream spans/counters to this .trace.jsonl "
                          "(render with python -m repro.obs to-perfetto)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.trace:
         from repro import obs

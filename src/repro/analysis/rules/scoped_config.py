@@ -5,15 +5,15 @@ float64 latencies).  The wrong way to get it is
 ``jax.config.update("jax_enable_x64", True)`` — a process-global flip
 that silently changes dtypes for *every other* jax user in the process:
 the Pallas kernels, the serving engine, the train step.  PR 6 scoped the
-requirement with ``with jax.experimental.enable_x64():`` around each
+requirement with ``with jax.enable_x64(True):`` around each
 entry point so the flag is restored on exit; this rule keeps it that way.
 
 Flags:
   * any call to ``jax.config.update(...)`` / ``config.update("jax_*")``,
   * assignments to ``jax.config.<flag>``,
-  * ``enable_x64()`` called as a plain expression instead of as a
-    ``with`` context manager (entering without the ``with`` leaks the
-    flipped state).
+  * ``enable_x64(...)`` / ``jax.enable_x64(...)`` called as a plain
+    expression instead of as a ``with`` context manager (entering
+    without the ``with`` leaks the flipped state).
 """
 
 from __future__ import annotations
@@ -36,11 +36,10 @@ def _attr_chain(node: ast.AST) -> str:
 
 
 def _enable_x64_names(tree: ast.Module) -> Set[str]:
-    """Local names bound to jax.experimental.enable_x64."""
+    """Local names bound to jax.enable_x64."""
     out: Set[str] = set()
     for node in ast.walk(tree):
-        if isinstance(node, ast.ImportFrom) and \
-                node.module in ("jax.experimental", "jax.experimental.x64"):
+        if isinstance(node, ast.ImportFrom) and node.module == "jax":
             for alias in node.names:
                 if alias.name == "enable_x64":
                     out.add(alias.asname or "enable_x64")
@@ -50,7 +49,7 @@ def _enable_x64_names(tree: ast.Module) -> Set[str]:
 class ScopedConfigRule(Rule):
     name = "scoped-config"
     description = ("jax.config mutations are forbidden; 64-bit mode must "
-                   "be entered via `with enable_x64():`")
+                   "be entered via `with jax.enable_x64(True):`")
 
     def check(self, project: Project) -> Iterable[Finding]:
         for mod in project.iter_modules():
@@ -76,12 +75,11 @@ class ScopedConfigRule(Rule):
                         message=(
                             "process-global jax.config.update() mutation; "
                             "scope the requirement with `with "
-                            "jax.experimental.enable_x64():` (or the "
+                            "jax.enable_x64(True):` (or the "
                             "matching context manager) so the flag is "
                             "restored on exit"))
-                elif isinstance(node.func, ast.Name) and \
-                        node.func.id in x64_names and \
-                        id(node) not in with_calls:
+                elif (chain == "jax.enable_x64" or chain in x64_names) \
+                        and id(node) not in with_calls:
                     yield self.finding(
                         mod, node.lineno, col=node.col_offset,
                         message=(

@@ -17,9 +17,10 @@ Every measurement is stamped with its provenance backend:
     not.  When jax itself is unavailable the same roofline is fed from
     an analytic tile-traffic model (``detail="analytic"``).
 
-The ladder degrades in that order: a backend that cannot run here falls
-to the next rung rather than failing — calibration must work on a
-laptop CI runner and a TPU host alike, only the provenance differs.
+``backend="auto"`` takes the highest rung that can run here, so
+calibration works on a laptop CI runner and a TPU host alike and only
+the provenance differs.  An explicit ``backend="measured"`` never
+degrades: without a TPU it raises.
 
 jax is imported lazily inside functions only: ``repro.calib`` must stay
 importable in fork-safe jax-free processes (see ``repro.analysis``'s
@@ -114,22 +115,23 @@ def _mm_blocks(wl: Workload, genome) -> Tuple[int, int, int]:
     """The genome's array-partitioning tiles as Pallas block shape.
 
     ``T1 = n1 * n2`` per loop is the paper's array-partitioning tile —
-    the exact analog of the BlockSpec block (DESIGN.md §2).  Clamped to
-    the problem dims the way ``kernels.matmul`` itself clamps.
+    the exact analog of the BlockSpec block (DESIGN.md §2).  Snapped to
+    the block rule Mosaic accepts (``kernels.matmul.legal_blocks``), the
+    same rule the TPU autotuner's genomes obey.
     """
+    from repro.kernels.matmul import legal_blocks
     M, N, K = _mm_dims(wl)
-    bm = max(1, min(int(genome.t1("i")), M))
-    bn = max(1, min(int(genome.t1("j")), N))
-    bk = max(1, min(int(genome.t1("k")), K))
-    return bm, bk, bn
+    return legal_blocks(int(genome.t1("i")), int(genome.t1("k")),
+                        int(genome.t1("j")), M, K, N)
 
 
 def _jax_platform() -> Optional[str]:
+    """The default JAX platform, or None where jax is not installed."""
     try:
         import jax
-        return jax.devices()[0].platform
-    except Exception:  # repro: ignore[bare-except] -- jax missing or no backend: the measurement ladder degrades to the analytic rung by design
+    except ImportError:
         return None
+    return jax.default_backend()
 
 
 # ------------------------------------------------------------------ #
@@ -200,39 +202,43 @@ def _analytic_costs(wl: Workload, genome) -> Tuple[float, float]:
 def _hlo_rung(wl: Workload, genome, hw: HardwareProfile,
               cfg: MeasureConfig) -> Tuple[float, float, str]:
     """(estimate_us, compile_us, detail) — deterministic, no timing."""
-    if not cfg.analytic_only:
-        try:
-            from repro.launch.hlo_costs import analyze
-            fn, (a, b), blocks = _build_mm(wl, genome, interpret=True)
-            with get_tracer().span("calib.compile", cat="calib",
-                                   workload=wl.name, hlo=True):
-                t0 = time.perf_counter()
-                hlo = fn.lower(a, b).compile().as_text()
-                compile_us = (time.perf_counter() - t0) * 1e6
-            costs = analyze(hlo)
-            return (_roofline_us(costs.flops, costs.bytes, hw), compile_us,
-                    "hlo blocks=%dx%dx%d flops=%g bytes=%g"
-                    % (blocks + (costs.flops, costs.bytes)))
-        except Exception:  # repro: ignore[bare-except] -- no jax / lowering failed: fall through to the analytic rung, the ladder's documented fallback
-            pass
+    if not cfg.analytic_only and _jax_platform() is not None:
+        from repro.launch.hlo_costs import analyze
+        fn, (a, b), blocks = _build_mm(wl, genome, interpret=True)
+        with get_tracer().span("calib.compile", cat="calib",
+                               workload=wl.name, hlo=True):
+            t0 = time.perf_counter()
+            hlo = fn.lower(a, b).compile().as_text()
+            compile_us = (time.perf_counter() - t0) * 1e6
+        costs = analyze(hlo)
+        return (_roofline_us(costs.flops, costs.bytes, hw), compile_us,
+                "hlo blocks=%dx%dx%d flops=%g bytes=%g"
+                % (blocks + (costs.flops, costs.bytes)))
     flops, byts = _analytic_costs(wl, genome)
     return (_roofline_us(flops, byts, hw), 0.0,
             "analytic flops=%g bytes=%g" % (flops, byts))
 
 
 def _resolve_backend(wl: Workload, cfg: MeasureConfig) -> str:
-    """Pick the highest rung that can actually run here."""
+    """The rung to run: ``measured`` exactly when asked for or, under
+    ``auto``, when a TPU is here; otherwise the highest rung that can
+    run here."""
     want = cfg.backend
     if want not in BACKENDS + ("auto",):
         raise ValueError(f"unknown backend {want!r}; "
                          f"expected one of {BACKENDS + ('auto',)}")
     fam = workload_family(wl)
     plat = None if cfg.analytic_only else _jax_platform()
-    timable = fam == "mm" and plat is not None
-    if want in ("auto", "measured") and timable and plat != "cpu":
+    if want == "measured":
+        if fam != "mm" or plat != "tpu":
+            raise RuntimeError(
+                "backend='measured' times the Pallas matmul on a TPU; "
+                f"here the workload family is {fam!r} and the JAX "
+                f"platform is {plat or 'unavailable'}")
         return "measured"
-    if want == "measured" and timable:
-        want = "interpret"         # no accelerator: degrade one rung
+    timable = fam == "mm" and plat is not None
+    if want == "auto" and timable and plat == "tpu":
+        return "measured"
     if want in ("auto", "interpret") and timable and \
             wl.total_macs() <= cfg.interpret_max_macs:
         return "interpret"

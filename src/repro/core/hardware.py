@@ -99,3 +99,17 @@ TPU_V5E = HardwareProfile(
 )
 
 DTYPE_BYTES = {"fp32": 4, "bf16": 2, "int8": 1}
+
+# ``device_kind`` as JAX reports it -> the profile of that chip
+DEVICE_PROFILES = {"TPU v5 lite": TPU_V5E}
+
+
+def profile_for_device(device_kind: str) -> HardwareProfile:
+    """The profile of a JAX device kind; an unknown kind is an error, never
+    a default."""
+    try:
+        return DEVICE_PROFILES[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no hardware profile for device kind {device_kind!r}; "
+            f"known: {sorted(DEVICE_PROFILES)}") from None

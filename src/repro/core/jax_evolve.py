@@ -45,7 +45,7 @@ population every generation (dedup is a host-side hash structure), so
 ``evals`` reports ``chains * population * (epochs_run + 1)`` — the count
 actually computed.
 
-Dtype policy: every entry point runs under ``jax.experimental.enable_x64``
+Dtype policy: every entry point runs under ``jax.enable_x64``
 (see ``jax_model``); genomes stay int64 end-to-end, divisions that must
 round (tile counts, the random-mutation compensation ``ceil(va*vb/s)``)
 go through float64 exactly like the NumPy legalizer.
@@ -66,7 +66,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from .design_space import (_divisors_gt1, _divisors_t, _pow2_floor,
                            _simd_opts, _snap_tables, genome_from_row,
@@ -400,7 +400,7 @@ def evolve_jax(ops: JaxEngineOps, cfg: EvoConfig, seeds: Sequence = (),
     else:
         seg_len = max(1, epochs)
 
-    with enable_x64():
+    with enable_x64(True):
         prep, run = ops.get_runner(B, P, E, cfg.crossover_rate,
                                    cfg.mutation_alpha)
         keys = jax.random.split(jax.random.PRNGKey(cfg.seed), K)
@@ -469,7 +469,7 @@ def simulated_annealing_jax(ops: JaxEngineOps, max_evals: int = 3000,
     R = max(1, min(chains, max_evals))
     steps = max(0, (max_evals - R) // R) if R > 1 else max_evals
     t0 = time.perf_counter()
-    with enable_x64():
+    with enable_x64(True):
         sa_prep, sa_run = ops.get_sa(R, temperature, max(1, steps), alpha)
         key = jax.random.PRNGKey(seed)
         key, kinit = jax.random.split(key)

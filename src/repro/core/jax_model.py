@@ -13,7 +13,7 @@ elementwise chains, which may perturb the last ulp on some backends).
 
 Dtype policy (the 4096³ overflow guard, mirrored from the NumPy path):
 
-* every call runs under ``jax.experimental.enable_x64`` — without it JAX
+* every call runs under ``jax.enable_x64`` — without it JAX
   lowers the int64 genome matrices to int32, and the band prefix
   products alone reach ~7e10 at 4096³ scale (int32 wraps at 2.1e9);
 * integer arithmetic stays int64 exactly where the NumPy path is int64
@@ -36,7 +36,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from .perf_model import BatchPerformanceModel, _quartic
 
@@ -247,6 +247,6 @@ class JaxBatchModel:
     def fitness_matrix(self, mat: np.ndarray,
                        use_max_model: bool = False) -> np.ndarray:
         """Fitness of a ``[B, L, 3]`` int64 population matrix."""
-        with enable_x64():
+        with enable_x64(True):
             out = self._jit(mat, use_max=bool(use_max_model))
             return np.asarray(out)

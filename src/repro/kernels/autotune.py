@@ -8,6 +8,10 @@ epilogue structure with double buffering.  Non-divisor block shapes are
 first-class — edge blocks are padded, and the model charges the padding
 (``ceil`` grid terms), exactly like the paper's zero-padded non-divisor
 tiling.  The evolutionary engine is literally ``repro.core.evolutionary``.
+
+Every genome the search draws compiles for the chip: block dims obey
+Mosaic's (8, 128) rule (``matmul.legal_blocks``) and the block's VMEM
+limit, the one ``matmul`` passes to Mosaic, fits ``VMEM_LIMIT_MAX``.
 """
 
 from __future__ import annotations
@@ -27,7 +31,9 @@ from repro.core.evolutionary import EvoConfig, Problem, evolve
 from repro.core.hardware import TPU_V5E, HardwareProfile
 from repro.core.perf_model import _quartic
 
-from .matmul import MatmulConfig
+from .matmul import (LANE, SUBLANE, VMEM_LIMIT_MAX, MatmulConfig,
+                     legal_block, legal_blocks, vmem_bytes,
+                     vmem_limit_bytes)
 
 BlockGenome = Tuple[int, int, int, bool]  # (bm, bk, bn, k_innermost)
 
@@ -52,9 +58,16 @@ class TpuMatmulModel:
                 math.ceil(self.K / bk))
 
     def vmem_bytes(self, g: BlockGenome) -> int:
-        bm, bk, bn, _ = g
-        return (2 * (bm * bk + bk * bn) * self.dtype_bytes
-                + bm * bn * 4 + bm * bn * self.dtype_bytes)
+        bm, bk, bn, k_inner = g
+        return vmem_bytes(bm, bk, bn, self.dtype_bytes, k_inner,
+                          masked=self.K % bk != 0)
+
+    def vmem_limit_bytes(self, g: BlockGenome) -> int:
+        """The scoped-VMEM limit ``matmul`` passes to Mosaic for ``g``."""
+        return vmem_limit_bytes(self.vmem_bytes(g))
+
+    def feasible(self, g: BlockGenome) -> bool:
+        return self.vmem_limit_bytes(g) <= VMEM_LIMIT_MAX
 
     def block_compute_s(self, g: BlockGenome) -> float:
         bm, bk, bn, _ = g
@@ -85,9 +98,9 @@ class TpuMatmulModel:
 
     def fitness(self, g: BlockGenome) -> float:
         lat = self.latency_s(g)
-        v = self.vmem_bytes(g)
-        if v > self.hw.vmem_bytes:
-            lat *= _quartic(v / self.hw.vmem_bytes)
+        v = self.vmem_limit_bytes(g)
+        if v > VMEM_LIMIT_MAX:
+            lat *= _quartic(v / VMEM_LIMIT_MAX)
         return -lat
 
     def mfu(self, g: BlockGenome) -> float:
@@ -118,7 +131,7 @@ class TpuMatmulModel:
         tc = (2 * up(bm, 8) * up(bk, 128) * up(bn, 128)) / self.hw.flops_peak
         bytes_in = (bm * bk + bk * bn) * db
         bytes_out = np.where(k_inner, bm * bn * db / gk,
-                             np.float64(2 * bm * bn * 4))
+                             (2 * bm * bn * 4).astype(np.float64))
         td = (bytes_in + bytes_out) / self.hw.hbm_bw \
             + self.hw.dma_overhead_cycles / self.hw.freq_hz
 
@@ -126,24 +139,46 @@ class TpuMatmulModel:
         epilogue = (bm * bn * db) / self.hw.hbm_bw
         lat = td + tc + (n_blocks - 1) * np.maximum(tc, td) + epilogue
 
-        vmem = (2 * (bm * bk + bk * bn) * db + bm * bn * 4 + bm * bn * db)
-        lat = np.where(vmem > self.hw.vmem_bytes,
-                       lat * _quartic(vmem / self.hw.vmem_bytes), lat)
+        limit = vmem_limit_bytes(vmem_bytes(bm, bk, bn, db, k_inner,
+                                            masked=self.K % bk != 0))
+        lat = np.where(limit > VMEM_LIMIT_MAX,
+                       lat * _quartic(limit / VMEM_LIMIT_MAX), lat)
         return -lat
 
 
 class TpuMatmulProblem(Problem):
-    """core.evolutionary.Problem over Pallas block genomes."""
+    """core.evolutionary.Problem over Pallas block genomes.
+
+    ``sample``, ``mutate`` and ``crossover`` draw freely and then
+    :meth:`legalize`, so every genome they return compiles."""
 
     def __init__(self, model: TpuMatmulModel):
         self.model = model
         self.dims = (model.M, model.K, model.N)
 
+    def legalize(self, g: BlockGenome) -> BlockGenome:
+        """Snap ``g`` to Mosaic's block rule, then halve its largest
+        block dim until the VMEM limit fits."""
+        M, K, N = self.dims
+        blocks = list(legal_blocks(g[0], g[1], g[2], M, K, N))
+        granules = ((M, SUBLANE), (K, LANE), (N, LANE))
+        k_inner = g[3]
+        while not self.model.feasible((*blocks, k_inner)):
+            for i in sorted(range(3), key=lambda i: (blocks[i], i),
+                            reverse=True):
+                half = legal_block(blocks[i] // 2, *granules[i])
+                if half < blocks[i]:
+                    blocks[i] = half
+                    break
+            else:
+                break           # every dim at its smallest legal block
+        return (blocks[0], blocks[1], blocks[2], k_inner)
+
     def sample(self, rng: random.Random) -> BlockGenome:
         vals = []
         for d in self.dims:
             vals.append(rng.randint(1, min(d, 2048)))
-        return (vals[0], vals[1], vals[2], rng.random() < 0.9)
+        return self.legalize((vals[0], vals[1], vals[2], rng.random() < 0.9))
 
     def mutate(self, g: BlockGenome, rng: random.Random,
                alpha: float) -> BlockGenome:
@@ -159,12 +194,12 @@ class TpuMatmulProblem(Problem):
             vals[i] = rng.randint(1, min(self.dims[i], 2048))
         if rng.random() < 0.05:
             k_inner = not k_inner
-        return (vals[0], vals[1], vals[2], k_inner)
+        return self.legalize((vals[0], vals[1], vals[2], k_inner))
 
     def crossover(self, a: BlockGenome, b: BlockGenome,
                   rng: random.Random) -> BlockGenome:
         pick = lambda i: (a if rng.random() < 0.5 else b)[i]
-        return (pick(0), pick(1), pick(2), pick(3))
+        return self.legalize((pick(0), pick(1), pick(2), pick(3)))
 
     def fitness(self, g: BlockGenome) -> float:
         return self.model.fitness(g)
@@ -186,9 +221,8 @@ def _tune_matmul_cached(M: int, N: int, K: int, dtype_bytes: int,
     problem = TpuMatmulProblem(model)
     cfg = EvoConfig(population=48, parents=12, epochs=60, seed=seed,
                     max_evals=evals)
-    seeds = list(extra_seeds) + \
-        [(min(M, 256), min(K, 512), min(N, 256), True),
-         (min(M, 128), min(K, 128), min(N, 128), True)]
+    seeds = [problem.legalize(g) for g in list(extra_seeds) +
+             [(256, 512, 256, True), (128, 128, 128, True)]]
     res = evolve(problem, cfg, seeds=seeds)
     bm, bk, bn, k_inner = res.best
     return (MatmulConfig(bm=bm, bk=bk, bn=bn, k_innermost=k_inner),
@@ -227,7 +261,7 @@ def _block_entry(cfg: MatmulConfig, model: TpuMatmulModel) -> Dict:
     return {"bm": cfg.bm, "bk": cfg.bk, "bn": cfg.bn,
             "k_innermost": cfg.k_innermost,
             "latency_s": model.latency_s(g), "mfu": model.mfu(g),
-            "feasible": model.vmem_bytes(g) <= model.hw.vmem_bytes}
+            "feasible": model.feasible(g)}
 
 
 def resolve_matmul_config(M: int, N: int, K: int, dtype_bytes: int = 2,

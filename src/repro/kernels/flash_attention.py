@@ -95,12 +95,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
     kern = functools.partial(_kernel, scale=scale, causal=causal,
                              bq=bq, bkv=bkv, q_len=S, kv_len=T)
-    try:
-        params = dict(compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")))
-    except Exception:  # repro: ignore[bare-except] -- pallas param spellings differ across jax versions; empty params is the portable fallback
-        params = {}
 
     return pl.pallas_call(
         kern,
@@ -120,5 +114,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((bq, D), jnp.float32),   # output accumulator
         ],
         interpret=config.interpret,
-        **params,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
     )(q, k, v)

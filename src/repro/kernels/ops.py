@@ -1,9 +1,10 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On this CPU container the kernels execute through ``interpret=True`` (the
-Pallas interpreter runs the kernel body per grid step); on TPU the same code
-lowers through Mosaic.  ``set_interpret_default`` flips the global default so
-tests/examples run identically in both environments.
+A config left as ``None`` takes the mode of the platform, decided at the
+first call (never at import, so importing the kernels starts no JAX
+backend): a TPU lowers the kernels through Mosaic, the CPU runs them in
+the Pallas interpreter, and any other platform is an error.
+``set_interpret_default`` overrides that choice for tests.
 
 ``conv2d`` lowers convolution to im2col + the tunable matmul kernel — on TPU
 the MXU *is* the systolic array, so conv shares the tuned MM design exactly
@@ -23,38 +24,47 @@ from .flash_attention import FlashConfig, flash_attention
 from .matmul import MatmulConfig, matmul
 from .ssd import SSDConfig, ssd_chunk
 
-_INTERPRET_DEFAULT = jax.default_backend() != "tpu"
+_interpret_override: Optional[bool] = None
 
 
-def set_interpret_default(value: bool) -> None:
-    global _INTERPRET_DEFAULT
-    _INTERPRET_DEFAULT = value
+def set_interpret_default(value: Optional[bool]) -> None:
+    """Force interpret mode on or off; ``None`` returns the choice to the
+    platform."""
+    global _interpret_override
+    _interpret_override = value
 
 
 def interpret_default() -> bool:
-    return _INTERPRET_DEFAULT
+    """Whether a config left as ``None`` runs in the Pallas interpreter."""
+    if _interpret_override is not None:
+        return _interpret_override
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise RuntimeError(
+        f"no kernel path for platform {platform!r}: the Pallas kernels "
+        "lower through Mosaic on a TPU or run interpreted on the CPU")
 
 
 def _mm_cfg(config: Optional[MatmulConfig]) -> MatmulConfig:
-    cfg = config or MatmulConfig()
-    if cfg.interpret != _INTERPRET_DEFAULT and config is None:
-        cfg = MatmulConfig(interpret=_INTERPRET_DEFAULT)
-    return cfg
+    return config or MatmulConfig(interpret=interpret_default())
 
 
 def _fa_cfg(config: Optional[FlashConfig]) -> FlashConfig:
-    return config or FlashConfig(interpret=_INTERPRET_DEFAULT)
+    return config or FlashConfig(interpret=interpret_default())
 
 
 def _ssd_cfg(config: Optional[SSDConfig]) -> SSDConfig:
-    return config or SSDConfig(interpret=_INTERPRET_DEFAULT)
+    return config or SSDConfig(interpret=interpret_default())
 
 
 # The public wrappers resolve the interpret default *outside* jit: the
 # resolved (frozen, hashable) config is the static jit key, so a
 # ``set_interpret_default()`` flip after the first call retraces instead
 # of silently serving the stale mode from the jit cache (a ``config=None``
-# static key would pin whatever ``_INTERPRET_DEFAULT`` held at first trace).
+# static key would pin whatever mode held at first trace).
 
 @functools.partial(jax.jit, static_argnames=("config", "out_dtype"))
 def _matmul_jit(a, b, config: MatmulConfig, out_dtype):

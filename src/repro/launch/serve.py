@@ -17,6 +17,7 @@ import jax
 
 from repro.ckpt import latest_checkpoint, restore_params
 from repro.configs import ARCH_IDS, get_config, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.serve import SCHEDULERS, ServeConfig, make_engine
 from repro.serve.sim import poisson_requests
@@ -53,6 +54,7 @@ def main(argv=None):
                          "registry before serving; a replica against a "
                          "warm registry resolves all of them with 0 evals")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.trace:
         from repro import obs

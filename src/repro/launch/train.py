@@ -22,6 +22,7 @@ from repro.ckpt import AsyncCheckpointer, latest_checkpoint, \
     restore_checkpoint
 from repro.configs import ARCH_IDS, get_config, get_smoke_config
 from repro.data import DataConfig, SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.models import build_model
 from repro.parallel import plan as plan_lib
@@ -29,6 +30,25 @@ from repro.parallel.sharding import axis_rules, default_rules
 from repro.runtime import RestartPolicy, StragglerDetector, \
     run_with_restarts
 from repro.train import AdamWConfig, build_train_step, create_train_state
+from repro.train.step import abstract_train_state
+
+
+def state_shardings(model, opt: AdamWConfig, rules,
+                    ef_compression: bool = False):
+    """(abstract train state, its NamedShardings on ``rules.mesh``): the
+    one layout of the state, for a fresh start and a resume alike."""
+    template = abstract_train_state(model, opt, ef_compression)
+    specs = plan_lib.train_state_specs(template, rules)
+    return template, plan_lib.to_named(specs, rules)
+
+
+def fresh_train_state(model, opt: AdamWConfig, rules,
+                      ef_compression: bool = False, seed: int = 0):
+    """A new train state, created directly in its sharded layout."""
+    _, shardings = state_shardings(model, opt, rules, ef_compression)
+    return jax.jit(lambda: create_train_state(
+        model, opt, jax.random.key(seed), ef_compression),
+        out_shardings=shardings)()
 
 
 def main(argv=None):
@@ -46,6 +66,7 @@ def main(argv=None):
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--ef-compression", action="store_true")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = build_model(cfg)
@@ -65,16 +86,14 @@ def main(argv=None):
             step_fn = jax.jit(build_train_step(
                 model, opt, use_ef_compression=args.ef_compression))
             if resume:
-                template = jax.eval_shape(lambda: create_train_state(
-                    model, opt, jax.random.key(0), args.ef_compression))
-                specs = plan_lib.train_state_specs(template, rules)
-                state = restore_checkpoint(
-                    resume, template, plan_lib.to_named(specs, rules))
+                template, shardings = state_shardings(
+                    model, opt, rules, args.ef_compression)
+                state = restore_checkpoint(resume, template, shardings)
                 start = int(state["opt_state"]["step"])
                 print(f"[resume] from step {start}")
             else:
-                state = create_train_state(model, opt, jax.random.key(0),
-                                           args.ef_compression)
+                state = fresh_train_state(model, opt, rules,
+                                          args.ef_compression)
                 start = 0
             for i in range(start, args.steps):
                 t0 = time.perf_counter()

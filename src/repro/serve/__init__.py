@@ -1,6 +1,6 @@
 from .engine import (EngineBase, ServeConfig, ServingEngine,
                      build_prefill_step, build_decode_step,
-                     model_gemm_shapes)
+                     greedy_mismatches, model_gemm_shapes)
 from .continuous import ContinuousServingEngine
 from .stats import Request, RequestMetrics, ServeStats, as_requests
 
@@ -21,4 +21,5 @@ def make_engine(scheduler: str, model, params, cfg: ServeConfig,
 __all__ = ["ServeConfig", "ServingEngine", "ContinuousServingEngine",
            "EngineBase", "Request", "RequestMetrics", "ServeStats",
            "as_requests", "make_engine", "SCHEDULERS",
-           "build_prefill_step", "build_decode_step", "model_gemm_shapes"]
+           "build_prefill_step", "build_decode_step", "greedy_mismatches",
+           "model_gemm_shapes"]

@@ -95,6 +95,33 @@ def build_decode_step(model: Model) -> Callable:
     return decode
 
 
+def greedy_mismatches(model: Model, params, requests: List[Request],
+                      outs: List[np.ndarray]) -> List[str]:
+    """Served greedy tokens against a reference that uses no cache.
+
+    One ``model.forward`` over every request's prompt plus its served
+    tokens, right-padded into one batch (causal attention keeps each
+    position blind to the padding); the argmax at each position from the
+    last prompt token on must be the next served token.  Returns one
+    message per request that diverges, empty when all match."""
+    seqs = [np.concatenate([r.prompt, o]).astype(np.int32)
+            for r, o in zip(requests, outs)]
+    toks = np.zeros((len(seqs), max(len(s) for s in seqs)), np.int32)
+    for i, s in enumerate(seqs):
+        toks[i, :len(s)] = s
+    logits, _ = jax.jit(model.forward)(params, {"tokens": jnp.asarray(toks)})
+    pred = np.asarray(jnp.argmax(logits, -1))
+    bad = []
+    for i, (r, o) in enumerate(zip(requests, outs)):
+        p = len(r.prompt)
+        want = pred[i, p - 1:p - 1 + len(o)]
+        if not np.array_equal(want, o):
+            t = int(np.argmax(want != o))
+            bad.append(f"request {i}: served token {t} is {int(o[t])}, the "
+                       f"cache-free forward predicts {int(want[t])}")
+    return bad
+
+
 def _pad_cache_to(cache: Dict, T: int):
     """Right-pad the (stacked) KV time axis of a prefill cache to T."""
     def pad(x):

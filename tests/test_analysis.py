@@ -257,10 +257,15 @@ def test_scoped_config_catches_global_update(tmp_path):
 
 def test_scoped_config_with_enable_x64_is_legal(tmp_path):
     project = make_project(tmp_path, {"model.py": """\
-        from jax.experimental import enable_x64
+        import jax
+        from jax import enable_x64
 
         def fit():
-            with enable_x64():
+            with enable_x64(True):
+                return 1
+
+        def fit_qualified():
+            with jax.enable_x64(True):
                 return 1
     """})
     assert findings_of(ScopedConfigRule(), project) == []
@@ -268,16 +273,22 @@ def test_scoped_config_with_enable_x64_is_legal(tmp_path):
 
 def test_scoped_config_catches_unscoped_enable_x64_call(tmp_path):
     project = make_project(tmp_path, {"model.py": """\
-        from jax.experimental import enable_x64
+        import jax
+        from jax import enable_x64
 
         def fit():
-            ctx = enable_x64()
+            ctx = enable_x64(True)
+            ctx.__enter__()
+            return 1
+
+        def fit_qualified():
+            ctx = jax.enable_x64(True)
             ctx.__enter__()
             return 1
     """})
     findings = findings_of(ScopedConfigRule(), project)
-    assert len(findings) == 1
-    assert "outside a `with`" in findings[0].message
+    assert len(findings) == 2
+    assert all("outside a `with`" in f.message for f in findings)
 
 
 # ------------------------------------------------------------------ #

@@ -259,7 +259,7 @@ def test_jax_legalize_and_sample_match_numpy(tag, wl, df, opts):
     ``GenomeSpace.legalize_matrix`` on arbitrary raw level matrices, and
     compiled sampling emits only fixed points of the legalizer."""
     jax = _jax()
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     from repro.core.jax_evolve import JaxEngineOps
     _, batch, space = _soa_setup(wl, df, opts)
     ops = JaxEngineOps(space, batch)
@@ -270,7 +270,7 @@ def test_jax_legalize_and_sample_match_numpy(tag, wl, df, opts):
     legal = space.sample_matrix(random.Random(3), 100)
     raw[:100] = legal
     raw[:50, :, 1] *= rng.integers(1, 5, size=(50, ops.L), dtype=np.int64)
-    with enable_x64():
+    with enable_x64(True):
         got = np.asarray(jax.jit(ops._legalize)(raw))
         sampled = np.asarray(jax.jit(
             lambda k: ops._sample(k, 128))(jax.random.PRNGKey(0)))

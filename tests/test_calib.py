@@ -95,11 +95,29 @@ def test_workload_family_names():
 
 def test_ladder_degrades_to_hlo_estimate_without_jax():
     wl, res = _tiny_result()
-    for want in ("auto", "measured", "interpret", "hlo_estimate"):
+    for want in ("auto", "interpret", "hlo_estimate"):
         cfg = MeasureConfig(backend=want, analytic_only=True)
         assert _resolve_backend(wl, cfg) == "hlo_estimate"
+    with pytest.raises(RuntimeError, match="measured"):
+        _resolve_backend(wl, MeasureConfig(backend="measured",
+                                           analytic_only=True))
     with pytest.raises(ValueError):
         _resolve_backend(wl, MeasureConfig(backend="vibes"))
+
+
+def test_measured_backend_raises_without_a_chip():
+    """An explicit ``measured`` request never degrades to interpret or an
+    estimate: on a host whose JAX platform is not a TPU it raises, and
+    nothing is timed or stamped."""
+    jax = pytest.importorskip("jax")
+    if jax.default_backend() == "tpu":
+        pytest.skip("a TPU is attached: measured is available here")
+    wl, res = _tiny_result()
+    with pytest.raises(RuntimeError, match="measured.*TPU"):
+        measure_result(wl, res, U250, MeasureConfig(backend="measured"))
+    # auto still runs on the CPU, stamped with the rung it used
+    m = measure_result(wl, res, U250, MeasureConfig(backend="auto"))
+    assert m.backend == "interpret"
 
 
 def test_analytic_measurement_is_deterministic_and_stamped():

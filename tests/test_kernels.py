@@ -14,6 +14,7 @@ from repro.kernels import (FlashConfig, MatmulConfig, SSDConfig,
                            flash_attention, matmul, ref, ssd_chunk)
 from repro.kernels import ops
 from repro.kernels.autotune import TpuMatmulModel, tune_matmul
+from repro.kernels.matmul import VMEM_LIMIT_MAX
 
 
 # ---------------------------------------------------------------------- #
@@ -128,9 +129,9 @@ def test_autotuner_prefers_k_inner_and_fits_vmem():
     cfg = tune_matmul(2048, 2048, 2048)
     assert cfg.k_innermost        # Theorem 3.1 on TPU
     model = TpuMatmulModel(2048, 2048, 2048)
-    assert model.vmem_bytes((cfg.bm, cfg.bk, cfg.bn, cfg.k_innermost)) \
-        <= model.hw.vmem_bytes
-    assert model.mfu((cfg.bm, cfg.bk, cfg.bn, cfg.k_innermost)) > 0.5
+    g = (cfg.bm, cfg.bk, cfg.bn, cfg.k_innermost)
+    assert model.vmem_limit_bytes(g) <= VMEM_LIMIT_MAX
+    assert model.mfu(g) > 0.5
 
 
 def test_autotuner_model_k_outer_penalty():

@@ -44,6 +44,47 @@ def test_sample_always_legal(i, j, k, seed):
     _assert_legal(wl, space, g)
 
 
+def _assert_block_legal(model, g):
+    """Mosaic's block rule: bm a multiple of 8 or M, bk and bn multiples
+    of 128 or K and N; and the VMEM limit the kernel passes fits."""
+    bm, bk, bn, _ = g
+    for b, dim, align in ((bm, model.M, 8), (bk, model.K, 128),
+                          (bn, model.N, 128)):
+        assert 1 <= b <= dim and (b % align == 0 or b == dim), (g, dim)
+    assert model.feasible(g), g
+
+
+@given(st.integers(1, 5000), st.integers(1, 50000), st.integers(1, 5000),
+       st.integers(0, 2 ** 31))
+@SET
+def test_tpu_block_genomes_always_legal(M, N, K, seed):
+    """Every genome the TPU block search samples, mutates or crosses
+    compiles for the chip, for any problem dims."""
+    from repro.kernels.autotune import TpuMatmulModel, TpuMatmulProblem
+    model = TpuMatmulModel(M=M, N=N, K=K)
+    problem = TpuMatmulProblem(model)
+    rng = random.Random(seed)
+    pop = [problem.sample(rng) for _ in range(8)]
+    for _ in range(24):
+        a, b = rng.sample(pop, 2)
+        pop.append(problem.mutate(problem.crossover(a, b, rng), rng,
+                                  alpha=0.4))
+    for g in pop:
+        _assert_block_legal(model, g)
+
+
+def test_tpu_block_search_still_draws_non_divisor_tiles():
+    """Legal by construction does not mean divisors only: on 1000^3 the
+    sampler still draws aligned blocks that leave a ragged edge block."""
+    from repro.kernels.autotune import TpuMatmulModel, TpuMatmulProblem
+    problem = TpuMatmulProblem(TpuMatmulModel(M=1000, N=1000, K=1000))
+    rng = random.Random(0)
+    drawn = [problem.sample(rng) for _ in range(200)]
+    ragged = [g for g in drawn
+              if any(1000 % b for b in g[:3])]
+    assert len(ragged) > len(drawn) // 2
+
+
 @given(st.integers(4, 128), st.integers(0, 2 ** 31), st.integers(1, 60))
 @SET
 def test_mutation_chain_stays_legal(n, seed, steps):
@@ -162,12 +203,12 @@ def test_jax_legalize_never_out_of_space(seed, div_only):
     ports (so the compiled search can never walk out of the space)."""
     pytest.importorskip("jax")
     import numpy as np
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     from repro.core.design_space import genome_from_row
     wl, space, ops, leg = _jax_legalizer(div_only)
     rng = np.random.default_rng(seed)
     raw = rng.integers(-8, 4 * 96, size=(8, ops.L, 3)).astype(np.int64)
-    with enable_x64():
+    with enable_x64(True):
         out = np.asarray(leg(raw))
     for row in out:
         g = genome_from_row(row, ops.names)

@@ -13,7 +13,7 @@ import numpy as np
 from repro.configs import get_smoke_config
 from repro.models import build_model
 from repro.serve import (ContinuousServingEngine, Request, ServeConfig,
-                         ServingEngine, make_engine)
+                         ServingEngine, greedy_mismatches, make_engine)
 from repro.faults import FaultPlan, FaultSpec, injected
 from repro.serve.sim import (bursty_requests, countdown_model,
                              poisson_requests)
@@ -43,6 +43,24 @@ def test_greedy_generation_matches_manual_decode():
         manual.append(nxt)
         seq.append(nxt)
     assert list(out) == manual
+
+
+@pytest.mark.parametrize("scheduler", ["wave", "continuous"])
+def test_served_tokens_match_cache_free_reference(scheduler):
+    """The served-model check of ``chip_smoke.py`` at smoke size: every
+    request's greedy tokens equal the argmax of one cache-free forward."""
+    model, params, eng = _engine(scheduler=scheduler)
+    reqs = poisson_requests(8, rate_rps=0.0, vocab_size=model.cfg.vocab_size,
+                            prompt_len=range(2, 8), max_new_tokens=16, seed=0)
+    with jax.default_matmul_precision("highest"):
+        outs, stats = eng.serve(reqs)
+        assert [len(o) for o in outs] == [16] * 8
+        assert greedy_mismatches(model, params, reqs, outs) == []
+        # a corrupted token is reported, naming its request
+        outs[3] = outs[3].copy()
+        outs[3][5] = (outs[3][5] + 1) % model.cfg.vocab_size
+        bad = greedy_mismatches(model, params, reqs, outs)
+    assert len(bad) == 1 and bad[0].startswith("request 3: served token 5")
 
 
 def test_generation_batching_waves():
