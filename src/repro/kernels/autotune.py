@@ -23,6 +23,7 @@ import math
 import os
 import random
 import threading
+import time
 from typing import Dict, Optional, Sequence, Tuple
 
 import numpy as np
@@ -30,6 +31,7 @@ import numpy as np
 from repro.core.evolutionary import EvoConfig, Problem, evolve
 from repro.core.hardware import TPU_V5E, HardwareProfile
 from repro.core.perf_model import _quartic
+from repro.obs import get_metrics, get_tracer
 
 from .matmul import (LANE, SUBLANE, VMEM_LIMIT_MAX, MatmulConfig,
                      legal_block, legal_blocks, vmem_bytes,
@@ -266,17 +268,18 @@ def _block_entry(cfg: MatmulConfig, model: TpuMatmulModel) -> Dict:
 
 def resolve_matmul_config(M: int, N: int, K: int, dtype_bytes: int = 2,
                           registry=None, evals: int = 2000,
-                          seed: int = 0,
-                          stats: Optional[Dict[str, int]] = None
-                          ) -> MatmulConfig:
+                          seed: int = 0) -> MatmulConfig:
     """Block shape for (M, N, K): LRU -> disk registry -> warm-started tune.
 
     The call-time path the kernels use.  Exact registry hits return the
     cached shape with zero search evals; misses warm-start from the
     nearest cached matmul (dims clamped), tune, and record — so every
     replica sharing a registry root tunes each shape once, fleet-wide.
-    ``stats`` (optional dict) is incremented with the source of the
-    answer: ``lru_hits`` / ``disk_hits`` / ``tuned``.
+
+    Each call is a ``tuner.resolve`` span (``M, N, K, source, evals``)
+    and counts its source in ``obs.Metrics``: ``tuner.lru_hits``,
+    ``tuner.disk_hits`` or ``tuner.tuned``, with ``tuner.evals`` the
+    search evaluations spent and ``tuner.resolve_s`` its seconds.
 
     The LRU is keyed by (shape, dtype, registry root), so resolving
     against different registries never cross-talks and a registry-backed
@@ -285,10 +288,22 @@ def resolve_matmul_config(M: int, N: int, K: int, dtype_bytes: int = 2,
     is reused for the process lifetime — call :func:`tune_matmul` for a
     budget-controlled search.
     """
-    def count(source):
-        if stats is not None:
-            stats[source] = stats.get(source, 0) + 1
+    t0 = time.perf_counter()
+    with get_tracer().span("tuner.resolve", "tuner", M=M, N=N,
+                           K=K) as span:
+        cfg, source, spent = _resolve(M, N, K, dtype_bytes, registry,
+                                      evals, seed)
+        span.set(source=source, evals=spent)
+    metrics = get_metrics()
+    metrics.counter("tuner." + source)
+    metrics.counter("tuner.evals", spent)
+    metrics.observe("tuner.resolve_s", time.perf_counter() - t0)
+    return cfg
 
+
+def _resolve(M: int, N: int, K: int, dtype_bytes: int, registry,
+             evals: int, seed: int) -> Tuple[MatmulConfig, str, int]:
+    """(config, source, evals spent) for :func:`resolve_matmul_config`."""
     registry = registry if registry is not None else default_registry()
     key = (M, N, K, dtype_bytes,
            registry.root if registry is not None else None)
@@ -297,10 +312,10 @@ def resolve_matmul_config(M: int, N: int, K: int, dtype_bytes: int = 2,
         if hit is not None:
             _config_lru.move_to_end(key)
     if hit is not None:
-        count("lru_hits")
-        return hit
+        return hit, "lru_hits", 0
 
     fp = rec = None
+    spent = 0
     if registry is not None:
         from repro.registry import matmul_block_fingerprint
         fp = matmul_block_fingerprint(M, N, K, dtype_bytes, TPU_V5E)
@@ -310,7 +325,7 @@ def resolve_matmul_config(M: int, N: int, K: int, dtype_bytes: int = 2,
         cfg = MatmulConfig(bm=b["bm"], bk=b["bk"], bn=b["bn"],
                            k_innermost=b["k_innermost"])
         registry.touch(fp)
-        count("disk_hits")
+        source = "disk_hits"
     else:
         extra: Tuple[BlockGenome, ...] = ()
         if registry is not None:
@@ -320,7 +335,7 @@ def resolve_matmul_config(M: int, N: int, K: int, dtype_bytes: int = 2,
                 for _, r in registry.neighbors(fp, k=2))
         cfg, spent = _tune_matmul_cached(M, N, K, dtype_bytes, evals, seed,
                                          extra)
-        count("tuned")
+        source = "tuned"
         if registry is not None:
             from repro.registry import Record
             model = TpuMatmulModel(M=M, N=N, K=K, dtype_bytes=dtype_bytes)
@@ -334,7 +349,18 @@ def resolve_matmul_config(M: int, N: int, K: int, dtype_bytes: int = 2,
         _config_lru.move_to_end(key)
         while len(_config_lru) > _CONFIG_LRU_MAX:
             _config_lru.popitem(last=False)
-    return cfg
+    return cfg, source, spent
+
+
+TUNER_COUNTERS = ("tuned", "disk_hits", "lru_hits", "evals")
+
+
+def tuner_counts() -> Dict[str, int]:
+    """The process's ``tuner.*`` counters so far (resolutions by source,
+    and search evals spent): the difference of two readings counts what
+    happened between them."""
+    counters = get_metrics().counters
+    return {c: int(counters.get("tuner." + c, 0)) for c in TUNER_COUNTERS}
 
 
 def predicted_mfu(M: int, N: int, K: int, cfg: MatmulConfig,
@@ -362,21 +388,20 @@ def pretune_gemms(shapes: Sequence[Tuple[int, int, int]],
                   dtype_bytes: int = 2) -> Dict[str, int]:
     """Resolve a block config for every (M, N, K), warming LRU + registry.
 
-    Returns resolution-source counters (``shapes``/``tuned``/
-    ``disk_hits``/``lru_hits``): a warm second pass over the same shapes
-    against the same registry reports ``tuned == 0`` — every config
-    comes from the persistent store with zero search evals.
+    Returns resolution-source counts (``shapes``/``tuned``/
+    ``disk_hits``/``lru_hits``) and the search ``evals`` spent, read
+    from the ``tuner.*`` counters: a warm second pass over the same
+    shapes against the same registry reports ``tuned == evals == 0`` —
+    every config comes from the persistent store.
     """
     registry = registry if registry is not None else default_registry()
-    stats: Dict[str, int] = {}
+    before = tuner_counts()
     for (M, N, K) in shapes:
         resolve_matmul_config(M, N, K, dtype_bytes=dtype_bytes,
-                              registry=registry, evals=evals, seed=seed,
-                              stats=stats)
+                              registry=registry, evals=evals, seed=seed)
+    after = tuner_counts()
     return {"shapes": len(shapes),
-            "tuned": stats.get("tuned", 0),
-            "disk_hits": stats.get("disk_hits", 0),
-            "lru_hits": stats.get("lru_hits", 0)}
+            **{c: after[c] - before[c] for c in TUNER_COUNTERS}}
 
 
 def pretune_model_config(mcfg, batch: int, prefill_len: int,

@@ -156,6 +156,14 @@ def _kernel_k_outer(a_ref, b_ref, c_hbm, acc_ref, sem, *, bm: int, bn: int,
     store.wait()
 
 
+def kernel_name(M: int, N: int, K: int, bm: int, bk: int, bn: int,
+                k_innermost: bool) -> str:
+    """``matmul_{M}x{N}x{K}_{bm}x{bk}x{bn}_{ki|ko}``: the name the kernel's
+    custom call carries in the HLO and in a device trace."""
+    order = "ki" if k_innermost else "ko"
+    return f"matmul_{M}x{N}x{K}_{bm}x{bk}x{bn}_{order}"
+
+
 def resolve_config(M: int, N: int, K: int,
                    dtype_bytes: int = 2, registry=None) -> MatmulConfig:
     """Tuned block shape for (M, N, K) from the design registry.
@@ -219,18 +227,22 @@ def matmul(a: jax.Array, b: jax.Array,
         # grid sequential
         dims = ("arbitrary", "arbitrary", "arbitrary")
 
-    out = pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_spec,
-        out_shape=out_shape,
-        scratch_shapes=scratch,
-        interpret=config.interpret,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=dims,
-            vmem_limit_bytes=vmem_limit_bytes(need)),
-    )(a, b)
+    # the scope names the custom call's HLO instruction, which is the
+    # op's name in a device trace: one name per GEMM class
+    with jax.named_scope(kernel_name(M, N, K, bm, bk, bn,
+                                     config.k_innermost)):
+        out = pl.pallas_call(
+            kern,
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=out_spec,
+            out_shape=out_shape,
+            scratch_shapes=scratch,
+            interpret=config.interpret,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=dims,
+                vmem_limit_bytes=vmem_limit_bytes(need)),
+        )(a, b)
     if not config.k_innermost:
         out = out.reshape(gm, bmp, gn, bnp)[:, :bm, :, :bn]
         out = out.reshape(gm * bm, gn * bn)[:M, :N].astype(out_dtype)

@@ -19,10 +19,14 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.obs import install_compile_listener
+
 from . import ref
 from .flash_attention import FlashConfig, flash_attention
 from .matmul import MatmulConfig, matmul
 from .ssd import SSDConfig, ssd_chunk
+
+install_compile_listener()      # before the kernels' first jit
 
 _interpret_override: Optional[bool] = None
 

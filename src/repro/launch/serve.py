@@ -83,7 +83,8 @@ def main(argv=None):
             registry=tuning.store if tuning is not None else None)
         print(f"[serve] pretune: {stats['shapes']} layer GEMM shapes — "
               f"{stats['tuned']} tuned, {stats['disk_hits']} from "
-              f"registry, {stats['lru_hits']} from LRU")
+              f"registry, {stats['lru_hits']} from LRU, "
+              f"{stats['evals']} search evals")
         if tuning is None:
             print("[serve] pretune warning: no --registry-dir, configs "
                   "live only in this process's LRU")

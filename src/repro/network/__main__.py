@@ -100,7 +100,8 @@ def main(argv=None) -> int:
         stats = pretune_gemms(graph.gemm_shapes(), registry=registry)
         print(f"[network] pretune: {stats['shapes']} shapes — "
               f"{stats['tuned']} tuned, {stats['disk_hits']} from "
-              f"registry, {stats['lru_hits']} from LRU")
+              f"registry, {stats['lru_hits']} from LRU, "
+              f"{stats['evals']} search evals")
         if args.json:
             with open(args.json, "w") as f:
                 json.dump(stats, f, indent=2)

@@ -5,21 +5,23 @@ The :class:`Metrics` registry is the aggregate twin of the event-stream
 happen*, metrics answer *how often / how slow overall*.  Everything is
 cheap enough to leave on unconditionally — a counter bump is one dict
 add under a lock-free fast path (the GIL serializes it), a histogram
-observation one deque append.
+observation two deque appends.
 
-Histograms are **streaming**: an optional ``window`` keeps only the most
-recent N observations (the rolling TTFT / tokens-per-sec percentiles
-``ServeStats`` reports); unwindowed histograms keep everything.  Empty
-histograms summarize to a well-formed all-zero report — never raise —
-which is the contract the zero-completed-requests serving path relies
-on.
+Histograms are **streaming**: each observation is stamped with the
+``time.perf_counter`` at which it was recorded, and an optional
+``window`` keeps only the most recent N observations (the rolling TTFT
+/ tokens-per-sec percentiles ``ServeStats`` reports); unwindowed
+histograms keep everything.  Empty histograms summarize to a
+well-formed all-zero report — never raise — which is the contract the
+zero-completed-requests serving path relies on.
 """
 
 from __future__ import annotations
 
 import threading
+import time
 from collections import deque
-from typing import Dict, Iterable, Optional
+from typing import Dict, Iterable, List, Optional, Tuple
 
 
 def percentile(values, q: float) -> float:
@@ -43,14 +45,21 @@ class Histogram:
         self.name = name
         self.window = window
         self._vals: deque = deque(maxlen=window)
+        self._stamps: deque = deque(maxlen=window)
         self.count = 0                 # lifetime observations (window-free)
         self.total = 0.0
 
     def observe(self, value: float) -> None:
+        """Record ``value``, stamped now on ``time.perf_counter``."""
         v = float(value)
         self._vals.append(v)
+        self._stamps.append(time.perf_counter())
         self.count += 1
         self.total += v
+
+    def stamped(self) -> List[Tuple[float, float]]:
+        """The retained observations as ``(stamp, value)`` pairs."""
+        return list(zip(self._stamps, self._vals))
 
     def extend(self, values: Iterable[float]) -> None:
         for v in values:

@@ -31,12 +31,20 @@ order correctly):
 Spans are emitted on *exit* as complete events (Chrome "X" phase), so a
 trace is balanced by construction — ``obs.perfetto`` converts it 1:1 to
 the Chrome trace-event JSON Perfetto loads.
+
+Profiler sink: while a JAX profiler session is active in the process,
+every span is also a ``jax.profiler.TraceAnnotation`` (its ``args`` as
+the event's stats) and every instant a zero-length one, JSONL file or
+not.  They land on the host plane of the profiler trace, on the device
+trace's clock.  jax is never imported here: a process that has not
+imported it cannot have a profiler session.
 """
 
 from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 from typing import Dict, Optional
@@ -46,6 +54,24 @@ def _now_us() -> float:
     """Microseconds on the monotonic clock (comparable across the
     processes of one machine — CLOCK_MONOTONIC is boot-anchored)."""
     return time.monotonic_ns() / 1e3
+
+
+_annotation = None      # jax.profiler.TraceAnnotation, once jax is loaded
+
+
+def _annotation_class():
+    """``jax.profiler.TraceAnnotation`` if a profiler session is active
+    in this process, else None.  Looks jax up only where it is already
+    imported, so the check never imports it (and the fork-safe search
+    stays jax-free)."""
+    global _annotation
+    cls = _annotation
+    if cls is None:
+        profiler = sys.modules.get("jax.profiler")
+        if profiler is None:
+            return None
+        cls = _annotation = profiler.TraceAnnotation
+    return cls if cls.is_enabled() else None
 
 
 class _NullSpan:
@@ -59,28 +85,49 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **args) -> None:
+        """Arguments known only inside the span (dropped here)."""
+
 
 _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0")
+    """A span to the JSONL file (``tracer``), the profiler (``annotation``,
+    the TraceAnnotation class), or both."""
 
-    def __init__(self, tracer: "Tracer", name: str, cat: str, args: Dict):
+    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0", "_annotation",
+                 "_live")
+
+    def __init__(self, tracer: Optional["Tracer"], name: str, cat: str,
+                 args: Dict, annotation=None):
         self._tracer = tracer
         self._name = name
         self._cat = cat
         self._args = args
+        self._annotation = annotation
+
+    def set(self, **args) -> None:
+        """Add arguments known only inside the span."""
+        self._args.update(args)
 
     def __enter__(self):
+        if self._annotation is not None:
+            self._live = self._annotation(self._name)
+            self._live.__enter__()
         self._t0 = _now_us()
         return self
 
     def __exit__(self, *exc):
         t1 = _now_us()
-        self._tracer._emit({"ev": "span", "name": self._name,
-                            "cat": self._cat, "ts": self._t0,
-                            "dur": t1 - self._t0, "args": self._args})
+        if self._annotation is not None:
+            if self._args:
+                self._live.set_metadata(**self._args)
+            self._live.__exit__(*exc)
+        if self._tracer is not None:
+            self._tracer._emit({"ev": "span", "name": self._name,
+                                "cat": self._cat, "ts": self._t0,
+                                "dur": t1 - self._t0, "args": self._args})
         return False
 
 
@@ -101,12 +148,20 @@ class Tracer:
 
     # -- event API -------------------------------------------------------
     def span(self, name: str, cat: str = "", **args):
-        """Context manager; emits one complete span event on exit."""
+        """Context manager; emits one complete span event on exit, and
+        annotates the profiler trace while a session is active.  With
+        neither sink it is the shared no-op span."""
+        ann = _annotation_class()
         if not self.enabled:
-            return _NULL_SPAN
-        return _Span(self, name, cat, args)
+            return _NULL_SPAN if ann is None else \
+                _Span(None, name, cat, args, ann)
+        return _Span(self, name, cat, args, ann)
 
     def instant(self, name: str, cat: str = "", **args) -> None:
+        ann = _annotation_class()
+        if ann is not None:
+            with ann(name, **args):
+                pass
         if not self.enabled:
             return
         self._emit({"ev": "instant", "name": name, "cat": cat,

@@ -240,31 +240,34 @@ class ContinuousServingEngine(EngineBase):
 
         while queue or any(s.state != "free" for s in slots):
             now = time.perf_counter() - t0
-            if policed:
-                police_queue(now)
-                # deadline eviction of in-flight requests: a timed-out
-                # slot frees immediately (partial output kept) so a
-                # stuck/slow request can never wedge the slot forever
+            with tr.span("serve.schedule", cat="serve"):
+                if policed:
+                    police_queue(now)
+                    # deadline eviction of in-flight requests: a timed-out
+                    # slot frees immediately (partial output kept) so a
+                    # stuck/slow request can never wedge the slot forever
+                    for slot in slots:
+                        if slot.state == "free":
+                            continue
+                        dl = _deadline(slot.req)
+                        if dl is not None and now > dl:
+                            finish(slot, "timeout", now)
+                # --- admission: recycle free slots from the arrived queue
                 for slot in slots:
-                    if slot.state == "free":
+                    if slot.state != "free" or not queue \
+                            or queue[0][1].arrival_s > now:
                         continue
-                    dl = _deadline(slot.req)
-                    if dl is not None and now > dl:
-                        finish(slot, "timeout", now)
-            # --- admission: recycle free slots from the arrived queue --- #
-            for slot in slots:
-                if slot.state != "free" or not queue \
-                        or queue[0][1].arrival_s > now:
-                    continue
-                slot.req_idx, slot.req = queue.popleft()
-                slot.state = "prefill"
-                slot.pos = 0
-                slot.chunks = self._chunks_of(slot.req.prompt)
-                slot.cache = fresh_slot
-                slot.admit_s = now
-                tr.instant("serve.admit", cat="serve",
-                           request_id=slot.req.request_id, slot=slot.index,
-                           queue_wait_ms=(now - slot.req.arrival_s) * 1e3)
+                    slot.req_idx, slot.req = queue.popleft()
+                    slot.state = "prefill"
+                    slot.pos = 0
+                    slot.chunks = self._chunks_of(slot.req.prompt)
+                    slot.cache = fresh_slot
+                    slot.admit_s = now
+                    tr.instant("serve.admit", cat="serve",
+                               request_id=slot.req.request_id,
+                               slot=slot.index,
+                               queue_wait_ms=(now - slot.req.arrival_s)
+                               * 1e3)
             if tr.enabled:
                 tr.counter("serve.slots",
                            decode=sum(1 for s in slots
@@ -293,8 +296,6 @@ class ContinuousServingEngine(EngineBase):
                         self.params, slot.cache,
                         jnp.asarray(chunk[None, :].astype(np.int32)),
                         jnp.asarray([slot.pos], jnp.int32))
-                    if tr.enabled:   # time the dispatch, not the queue
-                        jax.block_until_ready(toks)
                 slot.pos += len(chunk)
                 prefill_chunks += 1
                 if slot.chunks:
@@ -307,7 +308,9 @@ class ContinuousServingEngine(EngineBase):
                 # have fixed length C, exact chunks end at their last row
                 last_row = (plen - 1) % len(chunk) if self._padded_chunks \
                     else len(chunk) - 1
-                first = int(np.asarray(toks)[0, last_row])
+                with tr.span("serve.first_token_harvest", cat="serve",
+                             slot=slot.index):
+                    first = int(np.asarray(toks)[0, last_row])
                 cache = self._insert_fn(cache, slot.cache,
                                         jnp.int32(slot.index))
                 slot.cache = None
@@ -352,7 +355,8 @@ class ContinuousServingEngine(EngineBase):
                         # is read-only); this D2H copy is the tick's one
                         # device sync, so the span brackets real work,
                         # not dispatch latency
-                        nxt_host = np.array(nxt_cur)[:, 0]
+                        with tr.span("serve.tick_harvest", cat="serve"):
+                            nxt_host = np.array(nxt_cur)[:, 0]
                     cur_dev, pos_dev, cache = nxt_cur, nxt_pos, nxt_cache
                     cur_host = nxt_host
                     decode_steps += 1

@@ -32,7 +32,9 @@ import numpy as np
 from repro.models.api import Model
 
 from .stats import Request, RequestMetrics, ServeStats, as_requests
-from repro.obs import get_tracer
+from repro.obs import get_tracer, install_compile_listener
+
+install_compile_listener()      # before the engines' first jit
 
 
 @dataclasses.dataclass
@@ -183,15 +185,15 @@ class EngineBase:
         at construction is cheaper than one jit compile; replicas after
         the first share everything from disk.
         """
-        from repro.kernels.autotune import resolve_matmul_config
-        stats: dict = {}
+        from repro.kernels.autotune import (resolve_matmul_config,
+                                            tuner_counts)
+        before = tuner_counts()
         for (M, N, K) in model_gemm_shapes(self.model.cfg, self.cfg):
             self.kernel_configs[(M, N, K)] = resolve_matmul_config(
-                M, N, K, registry=self.tuning.store, evals=self.tune_evals,
-                stats=stats)
-        self.kernel_stats = {
-            "shared": stats.get("disk_hits", 0) + stats.get("lru_hits", 0),
-            "tuned": stats.get("tuned", 0)}
+                M, N, K, registry=self.tuning.store, evals=self.tune_evals)
+        n = {s: c - before[s] for s, c in tuner_counts().items()}
+        self.kernel_stats = {"shared": n["disk_hits"] + n["lru_hits"],
+                             "tuned": n["tuned"]}
 
     def kernel_config(self, M: int, N: int, K: int):
         """Tuned MatmulConfig for an ad-hoc GEMM shape (LRU -> registry)."""

@@ -11,6 +11,8 @@ at import: one process at a time may load the TPU library, and every
 test worker imports this file.
 """
 
+import re
+
 import pytest
 
 jax = pytest.importorskip("jax")
@@ -45,6 +47,7 @@ def _compile(fn, one_chip, *shapes):
             for s, dt in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    return text
 
 
 @pytest.mark.parametrize("k_innermost", [True, False],
@@ -88,3 +91,24 @@ def test_conv2d_im2col_compiles_on_a_vgg16_layer(one_chip):
     _compile(lambda x, wt: ops.conv2d_op(x, wt, cfg), one_chip,
              ((1, h + p - 1, w + q - 1, ci), jnp.bfloat16),
              ((p, q, ci, co), jnp.bfloat16))
+
+
+def test_gemm_classes_carry_their_names(one_chip):
+    """Each tuned GEMM's custom call is the HLO instruction named for its
+    class: the op name a device trace shows (``.<n>`` aside)."""
+    one = MatmulConfig(bm=256, bk=512, bn=256)
+    two = MatmulConfig(bm=128, bk=256, bn=512, k_innermost=False)
+
+    def step(a, b, b2, c):
+        return [ops.matmul_op(a, w, one) for w in (b, b2)] + \
+            [ops.matmul_op(a, c, two)]
+    text = _compile(step, one_chip, ((512, 1024), jnp.bfloat16),
+                    ((1024, 512), jnp.bfloat16), ((1024, 512), jnp.bfloat16),
+                    ((1024, 1024), jnp.bfloat16))
+    calls = [line.split(" = ")[0].split()[-1].lstrip("%")
+             for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert sorted(re.sub(r"\.\d+$", "", c) for c in calls) == [
+        "matmul_512x1024x1024_128x256x512_ko",
+        "matmul_512x512x1024_256x512x256_ki",
+        "matmul_512x512x1024_256x512x256_ki"]

@@ -244,11 +244,11 @@ def test_kernel_pretune_warm_run_zero_evals(tmp_path):
     reset_config_lru()
     cold = pretune_model_config(cfg, batch=2, prefill_len=32,
                                 registry=store, evals=150)
-    assert cold["tuned"] == cold["shapes"] > 0
+    assert cold["tuned"] == cold["shapes"] > 0 and cold["evals"] > 0
     reset_config_lru()   # drop process memory: only the disk store remains
     warm = pretune_model_config(cfg, batch=2, prefill_len=32,
                                 registry=store, evals=150)
-    assert warm["tuned"] == 0
+    assert warm["tuned"] == warm["evals"] == 0
     assert warm["disk_hits"] == warm["shapes"] == cold["shapes"]
 
 

@@ -492,13 +492,15 @@ def test_resolve_lru_is_per_registry_root(store):
     """A registry-less resolution must not satisfy (and starve) a later
     registry-backed call for the same shape: the in-memory LRU is keyed
     by registry root, so the store is always reached at least once."""
-    from repro.kernels.autotune import _config_lru, resolve_matmul_config
+    from repro.kernels.autotune import (_config_lru, resolve_matmul_config,
+                                        tuner_counts)
     _config_lru.clear()
     no_reg = resolve_matmul_config(384, 384, 384, evals=300)   # no registry
-    stats: dict = {}
+    before = tuner_counts()
     with_reg = resolve_matmul_config(384, 384, 384, registry=store,
-                                     evals=300, stats=stats)
-    assert stats.get("lru_hits", 0) == 0          # LRU did not cross-talk
+                                     evals=300)
+    after = tuner_counts()
+    assert after["lru_hits"] == before["lru_hits"]  # LRU did not cross-talk
     assert with_reg == no_reg                     # same deterministic search
     fp = matmul_block_fingerprint(384, 384, 384, 2, TPU_V5E)
     assert store.get(fp) is not None              # fleet store was populated
