@@ -4,7 +4,9 @@ This is the faithful hardware adaptation (DESIGN.md §2): the genome is the
 Pallas block shape ``(bm, bk, bn)`` plus the grid permutation (k-innermost vs
 k-outermost), the resource constraint is VMEM instead of BRAM/DSP, and the
 latency model keeps the paper's prologue + steady-state max(compute, DMA) +
-epilogue structure with double buffering.  Non-divisor block shapes are
+epilogue structure with double buffering, and adds what each grid step
+costs beyond it, with constants measured on the chip
+(``benchmarks.step_cost``).  Non-divisor block shapes are
 first-class — edge blocks are padded, and the model charges the padding
 (``ceil`` grid terms), exactly like the paper's zero-padded non-divisor
 tiling.  The evolutionary engine is literally ``repro.core.evolutionary``.
@@ -38,6 +40,13 @@ from .matmul import (LANE, SUBLANE, VMEM_LIMIT_MAX, MatmulConfig,
                      vmem_limit_bytes)
 
 BlockGenome = Tuple[int, int, int, bool]  # (bm, bk, bn, k_innermost)
+
+# Fixed cost of one grid step beyond max(compute, DMA): pipeline
+# bookkeeping, DMA issue and wait (``benchmarks.step_cost``, TPU v5e).
+GRID_STEP_S = 2.08e-7
+# Cost per byte of the f32 accumulator read and written back on each
+# k-inner step after the first (``benchmarks.step_cost``, TPU v5e).
+ACC_RMW_S_PER_BYTE = 4.28e-14
 
 
 def _up(x: int, m: int) -> int:
@@ -81,22 +90,38 @@ class TpuMatmulModel:
         bm, bk, bn, k_inner = g
         gm, gn, gk = self.grid(g)
         bytes_in = (bm * bk + bk * bn) * self.dtype_bytes
-        if k_inner:
-            # C written once per (m, n) block; amortize over the k sweep
-            bytes_out = bm * bn * self.dtype_bytes / gk
-        else:
-            # dominated ordering: partial C spilled+reloaded per step (f32)
-            bytes_out = 2 * bm * bn * 4
+        # C written once per (m, n) block, amortized over the k sweep; the
+        # k-outer partial sums' round trip is waited (``step_cost_s``)
+        bytes_out = bm * bn * self.dtype_bytes / gk if k_inner else 0
         t = (bytes_in + bytes_out) / self.hw.hbm_bw
         return t + self.hw.dma_overhead_cycles / self.hw.freq_hz
 
-    def latency_s(self, g: BlockGenome) -> float:
+    def pipeline_s(self, g: BlockGenome) -> float:
+        """The paper's double-buffered pipeline: prologue + steady-state
+        max(compute, DMA) per block + epilogue."""
         gm, gn, gk = self.grid(g)
         n_blocks = gm * gn * gk
         tc, td = self.block_compute_s(g), self.block_dma_s(g)
         prologue = td
         epilogue = (g[0] * g[2] * self.dtype_bytes) / self.hw.hbm_bw
         return prologue + tc + (n_blocks - 1) * max(tc, td) + epilogue
+
+    def step_cost_s(self, g: BlockGenome) -> float:
+        """What the grid steps cost beyond the pipeline's max(compute,
+        DMA): a fixed cost per step, and the f32 accumulator's update,
+        in VMEM on each k-inner step after the first, or as the k-outer
+        partial sum's waited round trip through HBM on every step."""
+        bm, _, bn, k_inner = g
+        gm, gn, gk = self.grid(g)
+        acc = bm * bn * 4
+        if k_inner:
+            extra = gm * gn * (gk - 1) * acc * ACC_RMW_S_PER_BYTE
+        else:
+            extra = gm * gn * gk * (2 * acc / self.hw.hbm_bw)
+        return gm * gn * gk * GRID_STEP_S + extra
+
+    def latency_s(self, g: BlockGenome) -> float:
+        return self.pipeline_s(g) + self.step_cost_s(g)
 
     def fitness(self, g: BlockGenome) -> float:
         lat = self.latency_s(g)
@@ -132,14 +157,18 @@ class TpuMatmulModel:
 
         tc = (2 * up(bm, 8) * up(bk, 128) * up(bn, 128)) / self.hw.flops_peak
         bytes_in = (bm * bk + bk * bn) * db
-        bytes_out = np.where(k_inner, bm * bn * db / gk,
-                             (2 * bm * bn * 4).astype(np.float64))
+        bytes_out = np.where(k_inner, bm * bn * db / gk, 0.0)
         td = (bytes_in + bytes_out) / self.hw.hbm_bw \
             + self.hw.dma_overhead_cycles / self.hw.freq_hz
 
         n_blocks = gm * gn * gk
         epilogue = (bm * bn * db) / self.hw.hbm_bw
-        lat = td + tc + (n_blocks - 1) * np.maximum(tc, td) + epilogue
+        acc = bm * bn * 4
+        extra = np.where(k_inner,
+                         gm * gn * (gk - 1) * acc * ACC_RMW_S_PER_BYTE,
+                         n_blocks * (2 * acc / self.hw.hbm_bw))
+        lat = (td + tc + (n_blocks - 1) * np.maximum(tc, td) + epilogue) \
+            + (n_blocks * GRID_STEP_S + extra)
 
         limit = vmem_limit_bytes(vmem_bytes(bm, bk, bn, db, k_inner,
                                             masked=self.K % bk != 0))
@@ -276,10 +305,11 @@ def resolve_matmul_config(M: int, N: int, K: int, dtype_bytes: int = 2,
     nearest cached matmul (dims clamped), tune, and record — so every
     replica sharing a registry root tunes each shape once, fleet-wide.
 
-    Each call is a ``tuner.resolve`` span (``M, N, K, source, evals``)
-    and counts its source in ``obs.Metrics``: ``tuner.lru_hits``,
-    ``tuner.disk_hits`` or ``tuner.tuned``, with ``tuner.evals`` the
-    search evaluations spent and ``tuner.resolve_s`` its seconds.
+    Each call is a ``tuner.resolve`` span (``M, N, K, source, evals``,
+    and ``steps``, the picked block's grid step count) and counts its
+    source in ``obs.Metrics``: ``tuner.lru_hits``, ``tuner.disk_hits``
+    or ``tuner.tuned``, with ``tuner.evals`` the search evaluations spent
+    and ``tuner.resolve_s`` its seconds.
 
     The LRU is keyed by (shape, dtype, registry root), so resolving
     against different registries never cross-talks and a registry-backed
@@ -293,7 +323,9 @@ def resolve_matmul_config(M: int, N: int, K: int, dtype_bytes: int = 2,
                            K=K) as span:
         cfg, source, spent = _resolve(M, N, K, dtype_bytes, registry,
                                       evals, seed)
-        span.set(source=source, evals=spent)
+        gm, gn, gk = TpuMatmulModel(M=M, N=N, K=K).grid(
+            (cfg.bm, cfg.bk, cfg.bn, cfg.k_innermost))
+        span.set(source=source, evals=spent, steps=gm * gn * gk)
     metrics = get_metrics()
     metrics.counter("tuner." + source)
     metrics.counter("tuner.evals", spent)

@@ -30,6 +30,11 @@ from repro.core.workloads import Workload
 # Bump when the fingerprint *inputs* change meaning; old records become
 # unreachable (never silently reused against a different contract).
 FINGERPRINT_VERSION = 1
+# Version of the latency model ``kernels.autotune.TpuMatmulModel`` that a
+# ``tpu_block`` record was tuned under; bump it when the model changes,
+# so picks made under an older model are neither served nor used as
+# warm-start seeds.  2: the model charges each grid step's fixed cost.
+TPU_BLOCK_MODEL_VERSION = 2
 
 
 @dataclasses.dataclass(frozen=True)
@@ -112,6 +117,7 @@ def matmul_block_fingerprint(M: int, N: int, K: int, dtype_bytes: int,
     structure = {
         "kind": "tpu_block",
         "version": FINGERPRINT_VERSION,
+        "model": TPU_BLOCK_MODEL_VERSION,
         "dtype_bytes": dtype_bytes,
         "hw": _hw_payload(hw),
     }

@@ -23,10 +23,12 @@ from repro.kernels import FlashConfig, MatmulConfig, SSDConfig, ops  # noqa: E40
 from repro.kernels.autotune import (TpuMatmulModel, TpuMatmulProblem,  # noqa: E402
                                     tune_matmul)
 
-# (M, N, K): non-divisor, square-large, a wide prefill GEMM, and the
-# smollm-135m MLP up-projection (prefill) and LM head (decode)
+# (M, N, K): non-divisor, square-large, a wide prefill GEMM, the
+# smollm-135m MLP up-projection (prefill) and LM head (decode), and the
+# starcoder2-7b prefill up, down and head projections (large blocks)
 TUNED_SHAPES = [(1000, 1000, 1000), (4096, 4096, 4096), (8192, 1536, 576),
-                (1024, 1536, 576), (4, 49152, 576)]
+                (1024, 1536, 576), (4, 49152, 576), (4096, 18432, 4608),
+                (4096, 4608, 18432), (4096, 49152, 4608)]
 
 
 @pytest.fixture(scope="module")

@@ -140,3 +140,28 @@ def test_autotuner_model_k_outer_penalty():
     g_in = (256, 256, 256, True)
     g_out = (256, 256, 256, False)
     assert m.latency_s(g_out) > m.latency_s(g_in)
+
+
+def _steps(model, g):
+    gm, gn, gk = model.grid(g)
+    return gm * gn * gk
+
+
+def test_autotuner_charges_grid_steps_at_prefill():
+    """Each grid step's fixed cost moves a compute-bound GEMM (the
+    starcoder2-7b up projection at M 4096) off thousands of short
+    steps, within the VMEM cap."""
+    model = TpuMatmulModel(4096, 18432, 4608)
+    cfg = tune_matmul(4096, 18432, 4608)
+    g = (cfg.bm, cfg.bk, cfg.bn, cfg.k_innermost)
+    short = (512, 384, 1152, True)        # the pick before steps were priced
+    assert 8 * _steps(model, g) <= _steps(model, short)
+    assert model.vmem_limit_bytes(g) <= VMEM_LIMIT_MAX
+    assert model.latency_s(g) < model.latency_s(short)
+
+
+def test_autotuner_keeps_decode_steps_few():
+    """A byte-bound skinny GEMM (M 16) already runs few steps."""
+    model = TpuMatmulModel(16, 18432, 4608)
+    cfg = tune_matmul(16, 18432, 4608)
+    assert _steps(model, (cfg.bm, cfg.bk, cfg.bn, cfg.k_innermost)) <= 16

@@ -202,11 +202,13 @@ def test_tuner_resolve_span_carries_source_and_evals(tmp_path):
     resolve_matmul_config(512, 512, 512, registry=store, evals=300)
     reset_config_lru()
     resolve_matmul_config(512, 512, 512, registry=store, evals=300)
-    resolve_matmul_config(512, 512, 512, registry=store, evals=300)
+    cfg = resolve_matmul_config(512, 512, 512, registry=store, evals=300)
     obs.disable()
     spans = [e for e in obs.load_events(path)[0]
              if e["ev"] == "span" and e["name"] == "tuner.resolve"]
     assert len(spans) == 3
+    steps = -(-512 // cfg.bm) * -(-512 // cfg.bn) * -(-512 // cfg.bk)
+    assert [s["args"]["steps"] for s in spans] == [steps] * 3
     assert [s["args"]["source"] for s in spans] == \
         ["tuned", "disk_hits", "lru_hits"]
     assert spans[0]["args"]["evals"] > 0

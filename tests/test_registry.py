@@ -390,6 +390,33 @@ def test_resolve_matmul_config_hits_registry(store):
     assert tune_matmul(512, 512, 512, evals=300) == cfg  # legacy API intact
 
 
+def test_tpu_block_records_of_an_older_model_are_not_served(store):
+    """A ``tpu_block`` record tuned under an older latency model (its
+    structure has no model version) is neither an exact hit nor a
+    warm-start neighbor: the shape is tuned afresh."""
+    import dataclasses
+    from repro.kernels.autotune import _config_lru, resolve_matmul_config
+    from repro.registry.fingerprint import FINGERPRINT_VERSION, _digest
+    old = {"kind": "tpu_block", "version": FINGERPRINT_VERSION,
+           "dtype_bytes": 2, "hw": dataclasses.asdict(TPU_V5E)}
+    stale = {"bm": 128, "bk": 128, "bn": 128, "k_innermost": True,
+             "latency_s": 1e-9, "feasible": True}
+    for dims in ((512, 512, 512), (512, 512, 384)):
+        store.put(Record(
+            fingerprint=_digest(dict(old, dims=list(dims))),
+            family=_digest(old), features=[9.0, 9.0, 9.0],
+            workload="mm_old", kind="tpu_block", hardware=TPU_V5E.name,
+            best=stale, pareto=[], evals=1))
+    fp = matmul_block_fingerprint(512, 512, 512, 2, TPU_V5E)
+    assert fp.family != _digest(old)
+    assert store.get(fp) is None
+    assert store.neighbors(fp, k=2) == []
+
+    _config_lru.clear()
+    resolve_matmul_config(512, 512, 512, registry=store, evals=300)
+    assert store.get(fp).evals > 0          # tuned here, not served
+
+
 # ------------------------------------------------------------------ #
 # CLI
 # ------------------------------------------------------------------ #
