@@ -6,9 +6,10 @@ are tuning parameters owned by the Odyssey autotuner (`autotune`).
 """
 
 from .matmul import MatmulConfig, matmul
+from .gmm import GmmConfig, gmm
 from .flash_attention import FlashConfig, flash_attention
 from .ssd import SSDConfig, ssd_chunk
 from . import ops, ref, autotune
 
-__all__ = ["MatmulConfig", "matmul", "FlashConfig", "flash_attention",
+__all__ = ["MatmulConfig", "matmul", "GmmConfig", "gmm", "FlashConfig", "flash_attention",
            "SSDConfig", "ssd_chunk", "ops", "ref", "autotune"]

@@ -35,6 +35,7 @@ from repro.core.hardware import TPU_V5E, HardwareProfile
 from repro.core.perf_model import _quartic
 from repro.obs import get_metrics, get_tracer
 
+from .gmm import GmmConfig, max_tile_visits
 from .matmul import (LANE, SUBLANE, VMEM_LIMIT_MAX, MatmulConfig,
                      legal_block, legal_blocks, vmem_bytes,
                      vmem_limit_bytes)
@@ -242,6 +243,67 @@ class TpuMatmulProblem(Problem):
         return g
 
 
+@dataclasses.dataclass
+class TpuGmmModel:
+    """Latency model of the grouped kernel (``kernels/gmm.py``) for ``R``
+    routed rows in ``E`` groups of sizes known only at run time.
+
+    Each (group, m-tile) visit runs the k-inner matmul of one ``bm``-row
+    tile against its group's ``(K, N)`` weight, so the kernel is priced as
+    :class:`TpuMatmulModel` over ``visits(bm) * bm`` rows: its pipeline
+    and step costs per tile, and each visit's read of its expert's
+    weight blocks.  ``visits`` charges the partial tiles at group
+    boundaries at their full cost: one extra tile for every group but
+    the first, at most one per row."""
+
+    R: int
+    N: int
+    K: int
+    E: int
+    dtype_bytes: int = 2
+    hw: HardwareProfile = TPU_V5E
+
+    def visits(self, bm: int) -> int:
+        return max_tile_visits(self.R, min(self.E, self.R), bm)
+
+    def tiles_model(self, g: BlockGenome) -> TpuMatmulModel:
+        """The dense matmul model of the kernel's tile visits."""
+        return TpuMatmulModel(M=self.visits(g[0]) * g[0], N=self.N,
+                              K=self.K, dtype_bytes=self.dtype_bytes,
+                              hw=self.hw)
+
+    def grid(self, g: BlockGenome) -> Tuple[int, int, int]:
+        return self.tiles_model(g).grid(g)
+
+    def feasible(self, g: BlockGenome) -> bool:
+        return self.tiles_model(g).feasible(g)
+
+    def latency_s(self, g: BlockGenome) -> float:
+        mm = self.tiles_model(g)
+        return mm.pipeline_s(g) + mm.step_cost_s(g)
+
+    def fitness(self, g: BlockGenome) -> float:
+        return self.tiles_model(g).fitness(g)
+
+    def mfu(self, g: BlockGenome) -> float:
+        useful = 2 * self.R * self.N * self.K
+        return useful / self.hw.flops_peak / self.latency_s(g)
+
+    def fitness_batch(self, genomes: Sequence[BlockGenome]) -> np.ndarray:
+        return np.array([self.fitness(g) for g in genomes])
+
+
+class TpuGmmProblem(TpuMatmulProblem):
+    """Block genomes of the grouped kernel: k innermost, always."""
+
+    def __init__(self, model: TpuGmmModel):
+        self.model = model
+        self.dims = (model.R, model.K, model.N)
+
+    def legalize(self, g: BlockGenome) -> BlockGenome:
+        return super().legalize((g[0], g[1], g[2], True))
+
+
 @functools.lru_cache(maxsize=4096)
 def _tune_matmul_cached(M: int, N: int, K: int, dtype_bytes: int,
                         evals: int, seed: int,
@@ -266,11 +328,28 @@ def tune_matmul(M: int, N: int, K: int, dtype_bytes: int = 2,
     return _tune_matmul_cached(M, N, K, dtype_bytes, evals, seed, ())[0]
 
 
+@functools.lru_cache(maxsize=4096)
+def _tune_gmm_cached(R: int, N: int, K: int, E: int, dtype_bytes: int,
+                     evals: int, seed: int,
+                     extra_seeds: Tuple[BlockGenome, ...]
+                     ) -> Tuple[GmmConfig, int]:
+    """(config, evals_spent) of the grouped kernel's block search."""
+    model = TpuGmmModel(R=R, N=N, K=K, E=E, dtype_bytes=dtype_bytes)
+    problem = TpuGmmProblem(model)
+    cfg = EvoConfig(population=48, parents=12, epochs=60, seed=seed,
+                    max_evals=evals)
+    seeds = [problem.legalize(g) for g in list(extra_seeds) +
+             [(256, 512, 256, True), (128, 128, 128, True)]]
+    res = evolve(problem, cfg, seeds=seeds)
+    bm, bk, bn, _ = res.best
+    return GmmConfig(bm=bm, bk=bk, bn=bn), res.evals
+
+
 # ---------------------------------------------------------------------- #
 # Registry-backed resolution: in-memory LRU in front of the on-disk store
 # ---------------------------------------------------------------------- #
 _lru_lock = threading.Lock()
-_config_lru: "collections.OrderedDict[Tuple, MatmulConfig]" = \
+_config_lru: "collections.OrderedDict[Tuple, object]" = \
     collections.OrderedDict()
 _CONFIG_LRU_MAX = 4096
 
@@ -287,10 +366,14 @@ def default_registry():
     return RegistryStore(root) if root else None
 
 
-def _block_entry(cfg: MatmulConfig, model: TpuMatmulModel) -> Dict:
-    g = (cfg.bm, cfg.bk, cfg.bn, cfg.k_innermost)
-    return {"bm": cfg.bm, "bk": cfg.bk, "bn": cfg.bn,
-            "k_innermost": cfg.k_innermost,
+def _genome(cfg) -> BlockGenome:
+    """The search genome of a matmul or grouped config (the grouped
+    kernel is k-inner)."""
+    return (cfg.bm, cfg.bk, cfg.bn, getattr(cfg, "k_innermost", True))
+
+
+def _block_entry(g: BlockGenome, model) -> Dict:
+    return {"bm": g[0], "bk": g[1], "bn": g[2], "k_innermost": g[3],
             "latency_s": model.latency_s(g), "mfu": model.mfu(g),
             "feasible": model.feasible(g)}
 
@@ -318,13 +401,58 @@ def resolve_matmul_config(M: int, N: int, K: int, dtype_bytes: int = 2,
     is reused for the process lifetime — call :func:`tune_matmul` for a
     budget-controlled search.
     """
+    def fingerprint():
+        from repro.registry import matmul_block_fingerprint
+        return matmul_block_fingerprint(M, N, K, dtype_bytes, TPU_V5E)
+
+    def tune(extra):
+        return _tune_matmul_cached(M, N, K, dtype_bytes, evals, seed,
+                                   extra)
+
+    return _resolve((M, N, K, dtype_bytes), registry,
+                    TpuMatmulModel(M=M, N=N, K=K, dtype_bytes=dtype_bytes),
+                    lambda g: MatmulConfig(bm=g[0], bk=g[1], bn=g[2],
+                                           k_innermost=g[3]),
+                    fingerprint, tune, "tpu_block", dict(M=M, N=N, K=K))
+
+
+def resolve_gmm_config(R: int, N: int, K: int, E: int,
+                       dtype_bytes: int = 2, registry=None,
+                       evals: int = 2000, seed: int = 0) -> GmmConfig:
+    """Block shape of the grouped kernel for ``R`` routed rows times
+    ``E`` weights of (K, N): the same LRU -> registry -> tune path as
+    :func:`resolve_matmul_config`, under keys of their own (the LRU key
+    starts with ``"gmm"``; the registry fingerprint is of kind
+    ``tpu_gmm_block``), so a grouped record never serves or seeds a
+    matmul and the other way round.  The ``tuner.resolve`` span carries
+    ``kind="gmm"`` and ``E``; the counters are the matmul's."""
+    def fingerprint():
+        from repro.registry import gmm_block_fingerprint
+        return gmm_block_fingerprint(R, N, K, E, dtype_bytes, TPU_V5E)
+
+    def tune(extra):
+        return _tune_gmm_cached(R, N, K, E, dtype_bytes, evals, seed, extra)
+
+    return _resolve(("gmm", R, N, K, E, dtype_bytes), registry,
+                    TpuGmmModel(R=R, N=N, K=K, E=E, dtype_bytes=dtype_bytes),
+                    lambda g: GmmConfig(bm=g[0], bk=g[1], bn=g[2]),
+                    fingerprint, tune, "tpu_gmm_block",
+                    dict(kind="gmm", M=R, N=N, K=K, E=E))
+
+
+def _resolve(key: Tuple, registry, model, make_config, fingerprint, tune,
+             kind: str, span_args: Dict):
+    """The config for ``key``: LRU -> disk registry -> warm-started tune,
+    under a ``tuner.resolve`` span, counted in ``obs.Metrics``.
+    ``make_config`` builds a config from a genome; ``fingerprint()`` is
+    the registry's key; ``tune(seeds)`` searches, warm-started from
+    neighbours' genomes (legalized by the search); ``kind`` names the
+    records."""
     t0 = time.perf_counter()
-    with get_tracer().span("tuner.resolve", "tuner", M=M, N=N,
-                           K=K) as span:
-        cfg, source, spent = _resolve(M, N, K, dtype_bytes, registry,
-                                      evals, seed)
-        gm, gn, gk = TpuMatmulModel(M=M, N=N, K=K).grid(
-            (cfg.bm, cfg.bk, cfg.bn, cfg.k_innermost))
+    with get_tracer().span("tuner.resolve", "tuner", **span_args) as span:
+        cfg, source, spent = _lookup(key, registry, model, make_config,
+                                     fingerprint, tune, kind)
+        gm, gn, gk = model.grid(_genome(cfg))
         span.set(source=source, evals=spent, steps=gm * gn * gk)
     metrics = get_metrics()
     metrics.counter("tuner." + source)
@@ -333,12 +461,11 @@ def resolve_matmul_config(M: int, N: int, K: int, dtype_bytes: int = 2,
     return cfg
 
 
-def _resolve(M: int, N: int, K: int, dtype_bytes: int, registry,
-             evals: int, seed: int) -> Tuple[MatmulConfig, str, int]:
-    """(config, source, evals spent) for :func:`resolve_matmul_config`."""
+def _lookup(key: Tuple, registry, model, make_config, fingerprint, tune,
+            kind: str) -> Tuple[object, str, int]:
+    """(config, source, evals spent) for :func:`_resolve`."""
     registry = registry if registry is not None else default_registry()
-    key = (M, N, K, dtype_bytes,
-           registry.root if registry is not None else None)
+    key = key + (registry.root if registry is not None else None,)
     with _lru_lock:
         hit = _config_lru.get(key)
         if hit is not None:
@@ -349,33 +476,30 @@ def _resolve(M: int, N: int, K: int, dtype_bytes: int, registry,
     fp = rec = None
     spent = 0
     if registry is not None:
-        from repro.registry import matmul_block_fingerprint
-        fp = matmul_block_fingerprint(M, N, K, dtype_bytes, TPU_V5E)
+        fp = fingerprint()
         rec = registry.get(fp)
     if rec is not None:
         b = rec.best
-        cfg = MatmulConfig(bm=b["bm"], bk=b["bk"], bn=b["bn"],
-                           k_innermost=b["k_innermost"])
+        cfg = make_config((b["bm"], b["bk"], b["bn"],
+                           b.get("k_innermost", True)))
         registry.touch(fp)
         source = "disk_hits"
     else:
         extra: Tuple[BlockGenome, ...] = ()
         if registry is not None:
-            extra = tuple(
-                (min(r.best["bm"], M), min(r.best["bk"], K),
-                 min(r.best["bn"], N), r.best["k_innermost"])
-                for _, r in registry.neighbors(fp, k=2))
-        cfg, spent = _tune_matmul_cached(M, N, K, dtype_bytes, evals, seed,
-                                         extra)
+            extra = tuple((r.best["bm"], r.best["bk"], r.best["bn"],
+                           r.best.get("k_innermost", True))
+                          for _, r in registry.neighbors(fp, k=2))
+        cfg, spent = tune(extra)
         source = "tuned"
         if registry is not None:
             from repro.registry import Record
-            model = TpuMatmulModel(M=M, N=N, K=K, dtype_bytes=dtype_bytes)
             registry.put(Record(
                 fingerprint=fp.digest, family=fp.family,
                 features=list(fp.features), workload=fp.workload,
-                kind="tpu_block", hardware=TPU_V5E.name,
-                best=_block_entry(cfg, model), pareto=[], evals=spent))
+                kind=kind, hardware=TPU_V5E.name,
+                best=_block_entry(_genome(cfg), model), pareto=[],
+                evals=spent))
     with _lru_lock:
         _config_lru[key] = cfg
         _config_lru.move_to_end(key)
@@ -410,6 +534,7 @@ def reset_config_lru() -> None:
     with _lru_lock:
         _config_lru.clear()
     _tune_matmul_cached.cache_clear()
+    _tune_gmm_cached.cache_clear()
 
 
 # ---------------------------------------------------------------------- #

@@ -23,6 +23,7 @@ from repro.obs import install_compile_listener
 
 from . import ref
 from .flash_attention import FlashConfig, flash_attention
+from .gmm import GmmConfig, gmm
 from .matmul import MatmulConfig, matmul
 from .ssd import SSDConfig, ssd_chunk
 
@@ -56,6 +57,10 @@ def _mm_cfg(config: Optional[MatmulConfig]) -> MatmulConfig:
     return config or MatmulConfig(interpret=interpret_default())
 
 
+def _gmm_cfg(config: Optional[GmmConfig]) -> GmmConfig:
+    return config or GmmConfig(interpret=interpret_default())
+
+
 def _fa_cfg(config: Optional[FlashConfig]) -> FlashConfig:
     return config or FlashConfig(interpret=interpret_default())
 
@@ -79,6 +84,19 @@ def matmul_op(a: jax.Array, b: jax.Array,
               config: Optional[MatmulConfig] = None,
               out_dtype=None) -> jax.Array:
     return _matmul_jit(a, b, _mm_cfg(config), out_dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("config",))
+def _gmm_jit(lhs, rhs, group_sizes, config: GmmConfig):
+    return gmm(lhs, rhs, group_sizes, config)
+
+
+def gmm_op(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
+           config: Optional[GmmConfig] = None) -> jax.Array:
+    """Grouped matmul: rows of ``lhs`` (R, K) sorted by group, each
+    group's rows times its own ``rhs`` (E, K, N); ``group_sizes`` (E,)
+    int32 on the device."""
+    return _gmm_jit(lhs, rhs, group_sizes, _gmm_cfg(config))
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "scale", "config"))
@@ -129,6 +147,7 @@ def ssd_chunk_op(x, a, b, c, h0=None, config: Optional[SSDConfig] = None):
     return _ssd_chunk_jit(x, a, b, c, h0, _ssd_cfg(config))
 
 
-__all__ = ["matmul_op", "attention_op", "conv2d_op", "ssd_chunk_op",
-           "MatmulConfig", "FlashConfig", "SSDConfig", "ref",
+__all__ = ["matmul_op", "gmm_op", "attention_op", "conv2d_op",
+           "ssd_chunk_op", "MatmulConfig", "GmmConfig", "FlashConfig",
+           "SSDConfig", "ref",
            "set_interpret_default", "interpret_default"]

@@ -24,6 +24,19 @@ class ModelConfig:
     mrope: bool = False           # Qwen2-VL multimodal RoPE
     mrope_sections: Tuple[int, int, int] = (16, 24, 24)
 
+    # attention kinds per layer (published ``layer_types``): "full" or
+    # "sliding"; empty = every layer full.  A sliding layer's query at
+    # position i sees keys j with i - sliding_window < j <= i.
+    layer_types: Tuple[str, ...] = ()
+    sliding_window: int = 0
+    # YaRN RoPE on the full layers (``rope_type: "yarn"``; 0 = default
+    # RoPE everywhere); sliding layers keep default RoPE at rope_theta
+    yarn_factor: float = 0.0
+    yarn_original_max: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_attention_factor: float = 1.0
+
     # MLP
     mlp: str = "silu_glu"         # silu_glu | relu2 | gelu
     tie_embeddings: bool = False

@@ -7,11 +7,13 @@ which no-ops outside a mesh so the same code serves CPU smoke tests and the
 
 from __future__ import annotations
 
+import dataclasses
 import math
 from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.parallel.sharding import current_rules, shard
 from .config import ModelConfig
@@ -89,13 +91,90 @@ def _rope_freqs(hd: int, theta: float) -> jax.Array:
 def apply_rope(x: jax.Array, positions: jax.Array, theta: float
                ) -> jax.Array:
     """x: (B, S, H, hd); positions: (B, S) int32."""
-    hd = x.shape[-1]
-    freqs = _rope_freqs(hd, theta)                       # (hd/2,)
-    ang = positions[..., None].astype(jnp.float32) * freqs  # (B, S, hd/2)
-    cos = jnp.cos(ang)[:, :, None, :].astype(x.dtype)
-    sin = jnp.sin(ang)[:, :, None, :].astype(x.dtype)
+    freqs = _rope_freqs(x.shape[-1], theta)              # (hd/2,)
+    return _rotate(x, positions[..., None].astype(jnp.float32) * freqs)
+
+
+def _rotate(x: jax.Array, ang: jax.Array,
+            scale: Optional[jax.Array] = None) -> jax.Array:
+    """Rotate the two halves of each head of ``x`` (B, S, H, hd) by the
+    angles ``ang`` (B, S, hd/2), with cos and sin times ``scale``."""
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if scale is not None:
+        cos, sin = cos * scale, sin * scale
+    cos = cos[:, :, None, :].astype(x.dtype)
+    sin = sin[:, :, None, :].astype(x.dtype)
     x1, x2 = jnp.split(x, 2, axis=-1)
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
+
+
+def yarn_inv_freq(hd: int, theta: float, factor: float, original_max: int,
+                  beta_fast: float, beta_slow: float) -> np.ndarray:
+    """YaRN's frequencies (Hugging Face ``_compute_yarn_parameters``):
+    each frequency blends the original (extrapolated) and the ``factor``
+    times slower (interpolated) one by a linear ramp over the dims whose
+    wavelengths lie between ``beta_fast`` and ``beta_slow`` rotations of
+    the original context."""
+    pos = theta ** (np.arange(0, hd, 2, dtype=np.float64) / hd)
+
+    def dim_of(rotations: float) -> float:
+        return (hd * math.log(original_max / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(dim_of(beta_fast)), 0)
+    high = min(math.ceil(dim_of(beta_slow)), hd - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(hd // 2) - low) / (high - low), 0.0, 1.0)
+    extrapolated = 1.0 - ramp
+    return (1.0 / (factor * pos)) * ramp + (1.0 / pos) * extrapolated
+
+
+def layer_kinds(cfg: ModelConfig) -> Tuple[str, ...]:
+    """"full" or "sliding" for each layer."""
+    if not cfg.layer_types:
+        return ("full",) * cfg.num_layers
+    kinds = tuple(cfg.layer_types[:cfg.num_layers])
+    if len(kinds) < cfg.num_layers or set(kinds) - {"full", "sliding"}:
+        raise ValueError(f"{cfg.name}: layer_types {cfg.layer_types} for "
+                         f"{cfg.num_layers} layers of kinds full, sliding")
+    return kinds
+
+
+def layer_meta(cfg: ModelConfig) -> Optional[Dict[str, jax.Array]]:
+    """Per-layer attention settings of a stack with mixed layer kinds,
+    stacked over layers: ``window`` (the sliding window, or 2**30 on full
+    layers), ``inv_freq`` (hd/2 RoPE frequencies) and ``rope_scale``
+    (YaRN's attention factor on cos and sin).  None for a uniform stack,
+    whose layers take the config's single RoPE and no window."""
+    if not cfg.layer_types:
+        return None
+    hd = cfg.hd
+    default = 1.0 / (cfg.rope_theta ** (np.arange(0, hd, 2,
+                                                  dtype=np.float64) / hd))
+    yarn = cfg.yarn_factor > 0
+    full = yarn_inv_freq(hd, cfg.rope_theta, cfg.yarn_factor,
+                         cfg.yarn_original_max, cfg.yarn_beta_fast,
+                         cfg.yarn_beta_slow) if yarn else default
+    sliding = [k == "sliding" for k in layer_kinds(cfg)]
+    return {
+        "window": jnp.asarray([cfg.sliding_window if s else 1 << 30
+                               for s in sliding], jnp.int32),
+        "inv_freq": jnp.asarray(np.stack([default if s else full
+                                          for s in sliding]), jnp.float32),
+        "rope_scale": jnp.asarray(
+            [1.0 if s or not yarn else cfg.yarn_attention_factor
+             for s in sliding], jnp.float32)}
+
+
+def rope_layer(x: jax.Array, positions: jax.Array, cfg: ModelConfig,
+               lmeta: Optional[Dict[str, jax.Array]]) -> jax.Array:
+    """RoPE of one layer: the config's own, or the layer's table of a
+    mixed stack (``layer_meta`` indexed at the layer)."""
+    if lmeta is None:
+        return apply_rope(x, positions, cfg.rope_theta)
+    return _rotate(x, positions[..., None].astype(jnp.float32)
+                   * lmeta["inv_freq"], lmeta["rope_scale"])
 
 
 def apply_mrope(x: jax.Array, positions: jax.Array, theta: float,
@@ -117,11 +196,7 @@ def apply_mrope(x: jax.Array, positions: jax.Array, theta: float,
         sel[None, None, :].astype(jnp.int32) * jnp.ones(
             x.shape[:2] + (nfreq,), jnp.int32),
         axis=-1)
-    ang = pos * freqs
-    cos = jnp.cos(ang)[:, :, None, :].astype(x.dtype)
-    sin = jnp.sin(ang)[:, :, None, :].astype(x.dtype)
-    x1, x2 = jnp.split(x, 2, axis=-1)
-    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
+    return _rotate(x, pos * freqs)
 
 
 # ---------------------------------------------------------------------- #
@@ -135,11 +210,14 @@ def _repeat_kv(k: jax.Array, group: int) -> jax.Array:
 
 def full_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                    causal: bool,
-                   kv_mask: Optional[jax.Array] = None) -> jax.Array:
+                   kv_mask: Optional[jax.Array] = None,
+                   window: Optional[jax.Array] = None) -> jax.Array:
     """Direct attention.  q: (B, S, H, hd); k/v: (B, T, Hkv, hd).
 
     ``kv_mask``: optional (B, T) bool — False keys (e.g. left-pad rows of a
-    ragged serving batch) are excluded for every query."""
+    ragged serving batch) are excluded for every query.  ``window``: a
+    sliding window, keys more than ``window - 1`` positions before the
+    query are excluded."""
     B, S, H, hd = q.shape
     T, Hkv = k.shape[1], k.shape[2]
     k = _repeat_kv(k, H // Hkv)
@@ -151,6 +229,10 @@ def full_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         mask = (jnp.arange(T)[None, :]
                 <= jnp.arange(S)[:, None] + (T - S))
         logits = jnp.where(mask[None, None], logits, -1e30)
+    if window is not None:
+        near = (jnp.arange(T)[None, :]
+                > jnp.arange(S)[:, None] + (T - S) - window)
+        logits = jnp.where(near[None, None], logits, -1e30)
     if kv_mask is not None:
         logits = jnp.where(kv_mask[:, None, None, :], logits, -1e30)
     p = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
@@ -159,7 +241,8 @@ def full_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
 def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                       causal: bool, block: int = 1024,
-                      kv_mask: Optional[jax.Array] = None) -> jax.Array:
+                      kv_mask: Optional[jax.Array] = None,
+                      window: Optional[jax.Array] = None) -> jax.Array:
     """Flash-style online-softmax attention, scanned over KV blocks.
 
     Peak memory is O(S * block) instead of O(S * T); this is the pure-JAX
@@ -169,7 +252,8 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     B, S, H, hd = q.shape
     T, Hkv = k.shape[1], k.shape[2]
     if T <= block:
-        return full_attention(q, k, v, causal, kv_mask=kv_mask)
+        return full_attention(q, k, v, causal, kv_mask=kv_mask,
+                              window=window)
     group = H // Hkv
     nblk = (T + block - 1) // block
     pad = nblk * block - T
@@ -197,6 +281,8 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         mask = kpos < T
         if causal:
             mask = mask & (kpos <= qpos)
+        if window is not None:
+            mask = mask & (kpos > qpos - window)
         s = jnp.where(mask[None, None], s, -1e30)
         if kmc:
             s = jnp.where(kmc[0][:, None, None, :], s, -1e30)
@@ -237,7 +323,9 @@ def attn_init(key, cfg: ModelConfig, dtype) -> Params:
 
 
 def attn_qkv(p: Params, cfg: ModelConfig, x: jax.Array,
-             positions: jax.Array) -> Tuple[jax.Array, jax.Array, jax.Array]:
+             positions: jax.Array,
+             lmeta: Optional[Dict[str, jax.Array]] = None
+             ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     B, S, _ = x.shape
     hd = cfg.hd
     q = (x @ p["wq"]).reshape(B, S, cfg.num_heads, hd)
@@ -252,8 +340,8 @@ def attn_qkv(p: Params, cfg: ModelConfig, x: jax.Array,
     else:
         if positions.ndim == 3:  # mrope-shaped positions on a text model
             positions = positions[0]
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+        q = rope_layer(q, positions, cfg, lmeta)
+        k = rope_layer(k, positions, cfg, lmeta)
     return q, k, v
 
 
@@ -261,15 +349,17 @@ def attn_forward(p: Params, cfg: ModelConfig, x: jax.Array,
                  positions: jax.Array, causal: bool = True,
                  kv: Optional[Tuple[jax.Array, jax.Array]] = None,
                  return_kv: bool = False,
-                 kv_mask: Optional[jax.Array] = None):
+                 kv_mask: Optional[jax.Array] = None,
+                 lmeta: Optional[Dict[str, jax.Array]] = None):
     """Full-sequence attention.  If ``kv`` is given (cross attention), keys/
     values come from it instead of ``x``.  ``x`` may arrive seq-sharded
     (sequence-parallel residual); it is gathered here and the output is
     scattered back.  ``kv_mask`` (B, T) excludes padding keys (ragged
-    serving batches)."""
+    serving batches); ``lmeta`` is the layer's entry of ``layer_meta``
+    (its window and RoPE) in a stack of mixed layer kinds."""
     x = sp_gather(x)
     if kv is None:
-        q, k, v = attn_qkv(p, cfg, x, positions)
+        q, k, v = attn_qkv(p, cfg, x, positions, lmeta)
     else:
         B, S, _ = x.shape
         hd = cfg.hd
@@ -281,7 +371,9 @@ def attn_forward(p: Params, cfg: ModelConfig, x: jax.Array,
     if return_kv:
         k = shard(k, "batch", "seq", None, None)
         v = shard(v, "batch", "seq", None, None)
-    out = chunked_attention(q, k, v, causal=causal, kv_mask=kv_mask)
+    out = chunked_attention(q, k, v, causal=causal, kv_mask=kv_mask,
+                            window=None if lmeta is None
+                            else lmeta["window"])
     out = out.reshape(out.shape[0], out.shape[1], -1)
     out = sp_scatter(out @ p["wo"])
     if return_kv:
@@ -291,7 +383,8 @@ def attn_forward(p: Params, cfg: ModelConfig, x: jax.Array,
 
 def attn_decode(p: Params, cfg: ModelConfig, x: jax.Array,
                 cache_k: jax.Array, cache_v: jax.Array, pos: jax.Array,
-                kv_start: Optional[jax.Array] = None):
+                kv_start: Optional[jax.Array] = None,
+                lmeta: Optional[Dict[str, jax.Array]] = None):
     """Incremental attention over a slotted KV cache.
 
     x: (B, C, d) — C new tokens per row (C=1 is classic decode; C>1 is a
@@ -305,6 +398,8 @@ def attn_decode(p: Params, cfg: ModelConfig, x: jax.Array,
     [kv_start, pos+c].  Rows past ``pos+c`` are never read, so a caller may
     leave garbage beyond its write frontier (padded prefill chunks, parked
     serving slots) as long as it overwrites row p before pos reaches p.
+    On a sliding layer (``lmeta``, see ``layer_meta``) query c also skips
+    the rows at or before ``pos + c - window``; the cache keeps them.
     """
     B, C = x.shape[0], x.shape[1]
     hd = cfg.hd
@@ -323,8 +418,8 @@ def attn_decode(p: Params, cfg: ModelConfig, x: jax.Array,
         q = apply_mrope(q, posm, cfg.rope_theta, cfg.mrope_sections)
         k = apply_mrope(k, posm, cfg.rope_theta, cfg.mrope_sections)
     else:
-        q = apply_rope(q, posb, cfg.rope_theta)
-        k = apply_rope(k, posb, cfg.rope_theta)
+        q = rope_layer(q, posb, cfg, lmeta)
+        k = rope_layer(k, posb, cfg, lmeta)
     # write the new KV at positions [pos, pos+C) (per batch row)
     upd = jax.vmap(lambda c, s, i: jax.lax.dynamic_update_slice(
         c, s, (i, 0, 0)))
@@ -342,6 +437,8 @@ def attn_decode(p: Params, cfg: ModelConfig, x: jax.Array,
     wpos = pos[:, None] + jnp.arange(C)[None, :]                # (B, C)
     mask = (tpos <= wpos[:, :, None]) \
         & (tpos >= kv_start[:, None, None])                     # (B, C, T)
+    if lmeta is not None:
+        mask = mask & (tpos > wpos[:, :, None] - lmeta["window"])
     logits = jnp.where(mask[:, None, None, :, :], logits, -1e30)
     logits = shard(logits, "batch", None, None, None, "seq")
     pr = jax.nn.softmax(logits, axis=-1)
@@ -382,7 +479,8 @@ def mlp_forward(p: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
 
 
 # ---------------------------------------------------------------------- #
-# MoE layer (GShard-style capacity dispatch; EP over the model axis)
+# MoE layer: dropless on the grouped kernel; GShard capacity dispatch
+# where experts shard over the model axis
 # ---------------------------------------------------------------------- #
 def moe_init(key, cfg: ModelConfig, dtype) -> Params:
     d, f, e = cfg.d_model, cfg.moe_d_ff, cfg.moe_experts
@@ -403,7 +501,93 @@ def moe_init(key, cfg: ModelConfig, dtype) -> Params:
 
 
 def moe_forward(p: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
-    """x: (B, S, D).  Tokens are grouped per batch row (G=B) so the dispatch
+    """x: (B, S, D).  Dropless routed experts on the grouped kernel
+    (:func:`moe_dropless`), except under a mesh whose 'model' axis is
+    above 1, where experts shard over that axis and the GShard capacity
+    dispatch (:func:`moe_capacity`) keeps the expert tensors dense."""
+    rules = current_rules()
+    if rules is not None and rules.axis_size("model") > 1:
+        return moe_capacity(p, cfg, x)
+    return moe_dropless(p, cfg, x)
+
+
+def moe_route(p: Params, cfg: ModelConfig, x: jax.Array
+              ) -> Tuple[jax.Array, jax.Array]:
+    """Router of tokens ``x`` (T, D): (weights (T, k) f32, experts (T, k)
+    int32).  Logits in float32 at HIGHEST precision, softmax over the
+    experts, top-k, weights renormalised to sum to one."""
+    with jax.named_scope("moe.route"):
+        logits = jnp.dot(x.astype(jnp.float32),
+                         p["router"].astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        probs = jax.nn.softmax(logits, axis=-1)
+        weights, experts = jax.lax.top_k(probs, cfg.moe_top_k)
+        weights = weights / jnp.clip(weights.sum(-1, keepdims=True), 1e-9)
+    return weights, experts.astype(jnp.int32)
+
+
+def moe_dispatch(x: jax.Array, experts: jax.Array, num_experts: int
+                 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The (token, expert) pairs of ``experts`` (T, k) sorted by expert:
+    (order (T*k,), the pairs' indices in that order; group_sizes (E,);
+    rows (T*k, D), the tokens of ``x`` (T, D) in that order)."""
+    K = experts.shape[1]
+    with jax.named_scope("moe.dispatch"):
+        flat = experts.reshape(-1)
+        order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+        group_sizes = jnp.bincount(flat, length=num_experts
+                                   ).astype(jnp.int32)
+        # in bounds by construction: clip the indices, not fill the rows
+        rows = jnp.take(x, order // K, axis=0, mode="clip")
+    return order, group_sizes, rows
+
+
+def moe_gmm_configs(cfg: ModelConfig, rows: int, dtype_bytes: int):
+    """Tuned blocks of the grouped kernel for ``rows`` routed rows: (gate
+    and up, down), resolved through ``kernels.autotune`` at trace time."""
+    from repro.kernels import ops
+    from repro.kernels.autotune import resolve_gmm_config
+    interpret = ops.interpret_default()
+    D, F, E = cfg.d_model, cfg.moe_d_ff, cfg.moe_experts
+    return tuple(dataclasses.replace(
+        resolve_gmm_config(rows, n, k, E, dtype_bytes=dtype_bytes),
+        interpret=interpret) for n, k in ((F, D), (D, F)))
+
+
+def moe_dropless(p: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
+    """Every routed (token, expert) pair is computed: route, sort the
+    pairs by expert, gather their rows, grouped gate and up, SiLU-GLU,
+    grouped down, unsort, and sum each token's k outputs weighted by its
+    router weights (in float32)."""
+    from repro.kernels import ops
+    x = sp_gather(x)
+    B, S, D = x.shape
+    T, E, K = B * S, cfg.moe_experts, cfg.moe_top_k
+    R = T * K
+    xt = x.reshape(T, D)
+    weights, experts = moe_route(p, cfg, xt)
+    order, group_sizes, rows = moe_dispatch(xt, experts, E)
+    up, down = moe_gmm_configs(cfg, R, x.dtype.itemsize)
+    g = ops.gmm_op(rows, p["w_gate"], group_sizes, up)
+    u = ops.gmm_op(rows, p["w_up"], group_sizes, up)
+    h = (jax.nn.silu(g.astype(jnp.float32))
+         * u.astype(jnp.float32)).astype(x.dtype)
+    out = ops.gmm_op(h, p["w_down"], group_sizes, down)
+    with jax.named_scope("moe.combine"):
+        where = jnp.zeros((R,), jnp.int32).at[order].set(
+            jnp.arange(R, dtype=jnp.int32))
+        y = jnp.take(out, where, axis=0, mode="clip").reshape(T, K, D)
+        y = jnp.einsum("tk,tkd->td", weights, y.astype(jnp.float32))
+        y = y.astype(x.dtype).reshape(B, S, D)
+    if cfg.moe_shared_expert:
+        y = y + mlp_forward(p["shared"], cfg, x)
+    return sp_scatter(y)
+
+
+def moe_capacity(p: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
+    """x: (B, S, D).  GShard capacity dispatch: each expert takes at most
+    ``capacity_factor * k * S / E`` tokens of a batch row and drops the
+    rest.  Tokens are grouped per batch row (G=B) so the dispatch
     tensors shard over the batch axes while experts shard over 'model'."""
     x = sp_gather(x)
     B, S, D = x.shape

@@ -1,4 +1,5 @@
-"""Decoder-only transformer: dense, MoE (interleaved, EP) and VLM variants.
+"""Decoder-only transformer: dense, MoE (interleaved, EP) and VLM variants,
+with full and sliding-window layers mixed where the config says so.
 
 Layers are scan-stacked (params carry a leading L dim) so the lowered HLO is
 one rolled loop — essential to keep 80 dry-run compiles cheap — and the layer
@@ -62,15 +63,29 @@ def init_params(cfg: ModelConfig, key) -> Dict:
     return params
 
 
+def _group_meta(cfg: ModelConfig):
+    """``layers.layer_meta`` in scan-group layout (G, per, ...), or None."""
+    meta = L.layer_meta(cfg)
+    if meta is None:
+        return None
+    per = cfg.moe_interleave if cfg.moe_experts else 1
+    return {k: v.reshape((cfg.num_layers // per, per) + v.shape[1:])
+            for k, v in meta.items()}
+
+
+def _at(meta, i: int):
+    return None if meta is None else {k: v[i] for k, v in meta.items()}
+
+
 def _group_fwd(cfg: ModelConfig, gp: Dict, x, positions, causal: bool,
-               kv_mask=None):
+               kv_mask=None, meta=None):
     per = cfg.moe_interleave if cfg.moe_experts else 1
     kvs = []
     for i in range(per):
         lp = gp[f"l{i}"]
         h, kv = L.attn_forward(lp["attn"], cfg, L.rmsnorm(x, lp["ln1"]),
                                positions, causal=causal, return_kv=True,
-                               kv_mask=kv_mask)
+                               kv_mask=kv_mask, lmeta=_at(meta, i))
         x = x + h
         kvs.append(kv)
         y = L.rmsnorm(x, lp["ln2"])
@@ -122,14 +137,14 @@ def forward(cfg: ModelConfig, params, batch, want_cache: bool = False):
                              positions=positions,
                              kv_mask=batch.get("attn_mask"))
 
-    def scan_body(carry, gp):
-        x = carry
-        x, kv = body(gp, x)
+    def scan_body(carry, inp):
+        gp, meta = inp
+        x, kv = body(gp, carry, meta=meta)
         return x, kv if want_cache else None
 
     scan_fn = jax.checkpoint(scan_body,
                              policy=jax.checkpoint_policies.nothing_saveable)
-    x, kv = jax.lax.scan(scan_fn, x, params["layers"])
+    x, kv = jax.lax.scan(scan_fn, x, (params["layers"], _group_meta(cfg)))
     x = L.rmsnorm(x, params["final_norm"])
     logits = _logits(cfg, params, x)
     cache = None
@@ -160,13 +175,14 @@ def decode_step(cfg: ModelConfig, params, cache, tokens, pos,
     cv = cache["v"].reshape((G, per) + cache["v"].shape[1:])
 
     def scan_body(x, inp):
-        gp, ck_g, cv_g = inp
+        gp, ck_g, cv_g, meta = inp
         new_k, new_v = [], []
         for i in range(per):
             lp = gp[f"l{i}"]
             h, k_upd, v_upd = L.attn_decode(
                 lp["attn"], cfg, L.rmsnorm(x, lp["ln1"]),
-                ck_g[i], cv_g[i], pos, kv_start=kv_start)
+                ck_g[i], cv_g[i], pos, kv_start=kv_start,
+                lmeta=_at(meta, i))
             x = x + h
             new_k.append(k_upd)
             new_v.append(v_upd)
@@ -177,7 +193,8 @@ def decode_step(cfg: ModelConfig, params, cache, tokens, pos,
                 x = x + L.mlp_forward(lp["mlp"], cfg, y)
         return x, (jnp.stack(new_k), jnp.stack(new_v))
 
-    x, (nk, nv) = jax.lax.scan(scan_body, x, (params["layers"], ck, cv))
+    x, (nk, nv) = jax.lax.scan(scan_body, x, (params["layers"], ck, cv,
+                                              _group_meta(cfg)))
     x = L.rmsnorm(x, params["final_norm"])
     logits = _logits(cfg, params, x)
     cache = {"k": nk.reshape(cache["k"].shape),

@@ -214,11 +214,11 @@ def layer_gemm_slots(cfg) -> List[Tuple[str, int, int, int]]:
     return slots
 
 
-def _moe_expert_m(cfg, batch: int, seq: int) -> int:
-    """Tokens one expert processes per MoE layer (GShard capacity)."""
-    cap = max(1, int(cfg.capacity_factor * cfg.moe_top_k * seq
-                     / cfg.moe_experts))
-    return batch * cap
+def _moe_expert_m(cfg, tokens: int) -> int:
+    """Rows one expert's GEMM sees in a MoE layer over ``tokens`` tokens:
+    the mean routed rows, tokens * top_k / experts (rounded up), as the
+    dropless grouped kernel runs them."""
+    return max(1, -(-tokens * cfg.moe_top_k // cfg.moe_experts))
 
 
 def model_config_graph(cfg, batch: int = 1, prefill_len: int = 512,
@@ -229,7 +229,7 @@ def model_config_graph(cfg, batch: int = 1, prefill_len: int = 512,
 
     ``prefill`` GEMMs see ``batch * prefill_len`` token rows, ``decode``
     GEMMs ``decode_batch`` (default ``batch``) rows.  MoE expert GEMMs
-    use the per-expert capacity slice instead of the full token count.
+    see the mean routed rows of one expert instead of the token count.
     """
     decode_batch = decode_batch if decode_batch is not None else batch
     slots = layer_gemm_slots(cfg)
@@ -241,8 +241,7 @@ def model_config_graph(cfg, batch: int = 1, prefill_len: int = 512,
         for name, n_dim, k_dim, times in slots:
             m = m_tokens
             if name.startswith("moe.w"):
-                m = _moe_expert_m(cfg, batch, prefill_len) \
-                    if stage == "prefill" else decode_batch
+                m = _moe_expert_m(cfg, m_tokens)
             key = (stage, m, n_dim, k_dim)
             if key not in grouped:
                 grouped[key] = 0

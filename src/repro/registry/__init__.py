@@ -12,7 +12,8 @@ their cached neighbors.
     __main__.py     operator CLI: python -m repro.registry list|show|...
 """
 
-from .fingerprint import (Fingerprint, matmul_block_fingerprint, nearest,
+from .fingerprint import (Fingerprint, gmm_block_fingerprint,
+                          matmul_block_fingerprint, nearest,
                           workload_fingerprint)
 from .store import (DEFAULT_ROOT_ENV, Record, RegistryStore, SCHEMA_VERSION,
                     default_root)
@@ -22,6 +23,7 @@ from .service import TuningService
 
 __all__ = [
     "Fingerprint", "workload_fingerprint", "matmul_block_fingerprint",
+    "gmm_block_fingerprint",
     "nearest",
     "Record", "RegistryStore", "SCHEMA_VERSION", "default_root",
     "DEFAULT_ROOT_ENV",

@@ -132,6 +132,29 @@ def matmul_block_fingerprint(M: int, N: int, K: int, dtype_bytes: int,
     )
 
 
+def gmm_block_fingerprint(R: int, N: int, K: int, E: int, dtype_bytes: int,
+                          hw: HardwareProfile) -> Fingerprint:
+    """Fingerprint of the grouped kernel's block-shape tuning problem
+    (``R`` routed rows over ``E`` weights of (K, N)): a kind of its own,
+    so it is never comparable to a matmul's."""
+    structure = {
+        "kind": "tpu_gmm_block",
+        "version": FINGERPRINT_VERSION,
+        "model": TPU_BLOCK_MODEL_VERSION,
+        "dtype_bytes": dtype_bytes,
+        "hw": _hw_payload(hw),
+    }
+    family = _digest(structure)
+    exact = dict(structure)
+    exact["dims"] = [R, N, K, E]
+    return Fingerprint(
+        digest=_digest(exact),
+        family=family,
+        features=(math.log2(R), math.log2(N), math.log2(K), math.log2(E)),
+        workload=f"gmm_{R}x{N}x{K}_g{E}_b{dtype_bytes}",
+    )
+
+
 def nearest(fp: Fingerprint,
             candidates: Sequence[Tuple[Fingerprint, object]],
             k: int = 3,

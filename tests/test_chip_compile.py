@@ -114,3 +114,24 @@ def test_gemm_classes_carry_their_names(one_chip):
         "matmul_512x1024x1024_128x256x512_ko",
         "matmul_512x512x1024_256x512x256_ki",
         "matmul_512x512x1024_256x512x256_ki"]
+
+
+# (R, N, K, E): Mellum2's prefill expert classes (16 x 256 tokens, top-8
+# of 64) and its decode class at 12 slots
+GMM_SHAPES = [(32768, 896, 2304, 64), (32768, 2304, 896, 64),
+              (96, 896, 2304, 64)]
+
+
+@pytest.mark.parametrize("shape", GMM_SHAPES,
+                         ids=["x".join(map(str, s)) for s in GMM_SHAPES])
+def test_tuned_gmm_compiles_and_carries_its_name(one_chip, shape):
+    """The grouped kernel with the tuner's blocks: scalar-prefetched tile
+    table, a run-time grid bound, the expert's weight block by index."""
+    from repro.kernels.autotune import resolve_gmm_config
+    from repro.kernels.gmm import kernel_name
+    R, N, K, E = shape
+    cfg = resolve_gmm_config(R, N, K, E)
+    text = _compile(lambda a, b, g: ops.gmm_op(a, b, g, cfg), one_chip,
+                    ((R, K), jnp.bfloat16), ((E, K, N), jnp.bfloat16),
+                    ((E,), jnp.int32))
+    assert "%" + kernel_name(R, N, K, E, cfg.bm, cfg.bk, cfg.bn) in text
