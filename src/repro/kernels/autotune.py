@@ -251,10 +251,18 @@ class TpuGmmModel:
     Each (group, m-tile) visit runs the k-inner matmul of one ``bm``-row
     tile against its group's ``(K, N)`` weight, so the kernel is priced as
     :class:`TpuMatmulModel` over ``visits(bm) * bm`` rows: its pipeline
-    and step costs per tile, and each visit's read of its expert's
-    weight blocks.  ``visits`` charges the partial tiles at group
-    boundaries at their full cost: one extra tile for every group but
-    the first, at most one per row."""
+    and step costs per tile.  ``visits`` charges the partial tiles at
+    group boundaries at their full cost: one extra tile for every group
+    but the first, at most one per row.
+
+    The expert's weight is charged as the kernel fetches it.  Pallas
+    copies a block only when its index differs from the step before's,
+    and the weight's index is ``(group, k, n)``: with a block that holds
+    the whole K (one k-step, :meth:`weight_per_group`) the visits of one
+    group keep it, so each n-block's sweep fetches a group's weight once,
+    at the switch to that group, one step ahead of its first visit
+    (:meth:`per_group_s`).  With several k-steps the index changes every
+    step and each visit reads the weight (:meth:`per_visit_s`)."""
 
     R: int
     N: int
@@ -278,12 +286,46 @@ class TpuGmmModel:
     def feasible(self, g: BlockGenome) -> bool:
         return self.tiles_model(g).feasible(g)
 
-    def latency_s(self, g: BlockGenome) -> float:
+    def weight_per_group(self, g: BlockGenome) -> bool:
+        """Whether a group's weight block is fetched once per group
+        switch, not once per visit: the block holds the whole K."""
+        return g[1] >= self.K
+
+    def per_visit_s(self, g: BlockGenome) -> float:
+        """Every visit reads its weight blocks: the tiles' dense model."""
         mm = self.tiles_model(g)
         return mm.pipeline_s(g) + mm.step_cost_s(g)
 
+    def per_group_s(self, g: BlockGenome) -> float:
+        """One k-step a visit: each visit pays max(compute, rows in and
+        out), and each of the ``gn * min(E, R)`` group switches the part
+        of its rows' and weight's fetch, issued one step ahead, that the
+        step before's compute cannot hide."""
+        bm, bk, bn, _ = g
+        mm = self.tiles_model(g)
+        _, gn, _ = mm.grid(g)
+        steps = gn * self.visits(bm)
+        switches = gn * min(self.E, self.R)
+        tc = mm.block_compute_s(g)
+        td_w = bk * bn * self.dtype_bytes / self.hw.hbm_bw
+        td_rows = mm.block_dma_s(g) - td_w
+        exposed = max(0.0, td_rows + td_w - tc)
+        prologue = td_rows + td_w
+        epilogue = bm * bn * self.dtype_bytes / self.hw.hbm_bw
+        return (prologue + steps * max(tc, td_rows)
+                + (switches - 1) * exposed + epilogue + mm.step_cost_s(g))
+
+    def latency_s(self, g: BlockGenome) -> float:
+        if self.weight_per_group(g):
+            return self.per_group_s(g)
+        return self.per_visit_s(g)
+
     def fitness(self, g: BlockGenome) -> float:
-        return self.tiles_model(g).fitness(g)
+        lat = self.latency_s(g)
+        v = self.tiles_model(g).vmem_limit_bytes(g)
+        if v > VMEM_LIMIT_MAX:
+            lat *= _quartic(v / VMEM_LIMIT_MAX)
+        return -lat
 
     def mfu(self, g: BlockGenome) -> float:
         useful = 2 * self.R * self.N * self.K
@@ -425,7 +467,9 @@ def resolve_gmm_config(R: int, N: int, K: int, E: int,
     starts with ``"gmm"``; the registry fingerprint is of kind
     ``tpu_gmm_block``), so a grouped record never serves or seeds a
     matmul and the other way round.  The ``tuner.resolve`` span carries
-    ``kind="gmm"`` and ``E``; the counters are the matmul's."""
+    ``kind="gmm"`` and ``E``, and of the pick its tile ``visits`` and
+    ``weight_per_group`` (whether the weight was charged once a group
+    switch); the counters are the matmul's."""
     def fingerprint():
         from repro.registry import gmm_block_fingerprint
         return gmm_block_fingerprint(R, N, K, E, dtype_bytes, TPU_V5E)
@@ -433,27 +477,32 @@ def resolve_gmm_config(R: int, N: int, K: int, E: int,
     def tune(extra):
         return _tune_gmm_cached(R, N, K, E, dtype_bytes, evals, seed, extra)
 
-    return _resolve(("gmm", R, N, K, E, dtype_bytes), registry,
-                    TpuGmmModel(R=R, N=N, K=K, E=E, dtype_bytes=dtype_bytes),
+    model = TpuGmmModel(R=R, N=N, K=K, E=E, dtype_bytes=dtype_bytes)
+    return _resolve(("gmm", R, N, K, E, dtype_bytes), registry, model,
                     lambda g: GmmConfig(bm=g[0], bk=g[1], bn=g[2]),
                     fingerprint, tune, "tpu_gmm_block",
-                    dict(kind="gmm", M=R, N=N, K=K, E=E))
+                    dict(kind="gmm", M=R, N=N, K=K, E=E),
+                    lambda g: dict(visits=model.visits(g[0]),
+                                   weight_per_group=model.weight_per_group(g)))
 
 
 def _resolve(key: Tuple, registry, model, make_config, fingerprint, tune,
-             kind: str, span_args: Dict):
+             kind: str, span_args: Dict, pick_args=None):
     """The config for ``key``: LRU -> disk registry -> warm-started tune,
     under a ``tuner.resolve`` span, counted in ``obs.Metrics``.
     ``make_config`` builds a config from a genome; ``fingerprint()`` is
     the registry's key; ``tune(seeds)`` searches, warm-started from
     neighbours' genomes (legalized by the search); ``kind`` names the
-    records."""
+    records; ``pick_args(genome)``, if given, adds the pick's own
+    attributes to the span."""
     t0 = time.perf_counter()
     with get_tracer().span("tuner.resolve", "tuner", **span_args) as span:
         cfg, source, spent = _lookup(key, registry, model, make_config,
                                      fingerprint, tune, kind)
-        gm, gn, gk = model.grid(_genome(cfg))
-        span.set(source=source, evals=spent, steps=gm * gn * gk)
+        g = _genome(cfg)
+        gm, gn, gk = model.grid(g)
+        span.set(source=source, evals=spent, steps=gm * gn * gk,
+                 **(pick_args(g) if pick_args else {}))
     metrics = get_metrics()
     metrics.counter("tuner." + source)
     metrics.counter("tuner.evals", spent)

@@ -35,6 +35,10 @@ FINGERPRINT_VERSION = 1
 # so picks made under an older model are neither served nor used as
 # warm-start seeds.  2: the model charges each grid step's fixed cost.
 TPU_BLOCK_MODEL_VERSION = 2
+# The same for ``tpu_gmm_block`` records and ``TpuGmmModel``.  3: the
+# expert's weight is charged once a group switch where a block holds
+# the whole K, not once a tile visit.
+TPU_GMM_BLOCK_MODEL_VERSION = 3
 
 
 @dataclasses.dataclass(frozen=True)
@@ -140,7 +144,7 @@ def gmm_block_fingerprint(R: int, N: int, K: int, E: int, dtype_bytes: int,
     structure = {
         "kind": "tpu_gmm_block",
         "version": FINGERPRINT_VERSION,
-        "model": TPU_BLOCK_MODEL_VERSION,
+        "model": TPU_GMM_BLOCK_MODEL_VERSION,
         "dtype_bytes": dtype_bytes,
         "hw": _hw_payload(hw),
     }

@@ -3,8 +3,10 @@
 * the grouped Pallas matmul (``ops.gmm_op``, interpret mode) against a
   per-group product: ragged groups, empty groups, one group taking every
   row, tiles that straddle groups; its gradient against ``ragged_dot``'s;
-* the tuner's grouped workload: legal blocks within the VMEM bound, and
-  keys of its own;
+* the tuner's grouped workload: legal blocks within the VMEM bound, keys
+  of its own, the expert's weight charged once a group switch where a
+  block holds the whole K (once a visit where it does not), and the
+  picks of Mellum2's prefill and decode classes;
 * the dropless expert layer against the float32 reference
   (``bench/reference/moe_transformer.py``), with no token dropped when
   every token picks the same experts; the capacity path only under a
@@ -162,6 +164,92 @@ def test_gmm_and_matmul_resolutions_do_not_collide(tmp_path):
     assert isinstance(g, GmmConfig) and hasattr(m, "k_innermost")
     kinds = sorted(r.kind for r in store.iter_records())
     assert kinds == ["tpu_block", "tpu_gmm_block"]
+
+
+# the grouped classes of Mellum2's expert layer at the MoE cell's 32768
+# prefill rows: (N, K) of gate and up, and of down
+CELL_CLASSES = [(896, 2304), (2304, 896)]
+
+
+@pytest.mark.parametrize("nk", CELL_CLASSES, ids=["gate_up", "down"])
+def test_gmm_weight_charged_once_a_group_switch_with_whole_k(nk):
+    """A block holding the whole K keeps a group's weight block across
+    its visits, so the model charges the weight at the group switches:
+    bm 128 costs well under the old charge of a weight read a visit."""
+    N, K = nk
+    model = TpuGmmModel(R=32768, N=N, K=K, E=64)
+    g = (128, K, N, True)
+    assert model.weight_per_group(g)
+    assert model.latency_s(g) == model.per_group_s(g)
+    assert model.latency_s(g) < 0.6 * model.per_visit_s(g)
+    # fewer groups, fewer switches to charge
+    assert TpuGmmModel(R=32768, N=N, K=K, E=32).per_group_s(g) < \
+        model.per_group_s(g)
+
+
+@pytest.mark.parametrize("nk", CELL_CLASSES, ids=["gate_up", "down"])
+def test_gmm_weight_charged_per_visit_with_split_k(nk):
+    """With several k-steps the weight's block index changes every step:
+    every visit reads it, as the model charged before."""
+    N, K = nk
+    model = TpuGmmModel(R=32768, N=N, K=K, E=64)
+    g = (128, K // 2, N, True)
+    assert not model.weight_per_group(g)
+    assert model.latency_s(g) == model.per_visit_s(g)
+    mm = model.tiles_model(g)
+    assert model.latency_s(g) == mm.pipeline_s(g) + mm.step_cost_s(g)
+
+
+@pytest.mark.parametrize("nk", CELL_CLASSES, ids=["gate_up", "down"])
+def test_gmm_cell_pick_pads_at_most_one_and_a_half(nk):
+    """The cell's classes leave bm 424 (1.82x the rows computed) for a
+    block that holds the whole K and N and pads at most 1.5x."""
+    N, K = nk
+    R = 32768
+    cfg = resolve_gmm_config(R, N, K, 64)
+    model = TpuGmmModel(R=R, N=N, K=K, E=64)
+    assert (cfg.bk, cfg.bn) == (K, N)
+    assert cfg.bm < 424
+    assert model.visits(cfg.bm) * cfg.bm / R <= 1.5
+
+
+# decode rows (slots x top-8) -> the pick's bm; on a TPU v5e each class
+# reads, us a call (PERF.md section 6):
+#   R 32: bm 8 146.3, 16 145.7, 24 145.8, 32 145.6
+#   R 96: bm 8 276.9, 16 272.3, 32 270.7, 40 272.0, 48 270.6, 96 271.1
+DECODE_PICKS = {32: 32, 96: 40}
+
+
+@pytest.mark.parametrize("nk", CELL_CLASSES, ids=["gate_up", "down"])
+@pytest.mark.parametrize("rows", list(DECODE_PICKS),
+                         ids=[f"R{r}" for r in DECODE_PICKS])
+def test_gmm_decode_picks(rows, nk):
+    """Mellum2's decode classes over 64 experts, weight-bound, hold the
+    whole K and N in one block.  At 4 slots (R 32) the pick is the
+    chip's fastest, as before.  At 12 slots (R 96), with more rows than
+    groups, it moved from bm 32 to 40, which reads 0.5% slower."""
+    N, K = nk
+    cfg = resolve_gmm_config(rows, N, K, 64)
+    assert (cfg.bm, cfg.bk, cfg.bn) == (DECODE_PICKS[rows], K, N)
+
+
+def test_gmm_resolve_span_carries_visits_and_weight_rule(tmp_path):
+    from repro import obs
+    from repro.kernels.autotune import reset_config_lru
+    path = str(tmp_path / "tuner.trace.jsonl")
+    reset_config_lru()
+    obs.configure(path)
+    try:
+        cfg = resolve_gmm_config(4096, 256, 512, 16)
+    finally:
+        obs.disable()
+    spans = [e for e in obs.load_events(path)[0]
+             if e["ev"] == "span" and e["name"] == "tuner.resolve"]
+    assert len(spans) == 1
+    args = spans[0]["args"]
+    assert args["kind"] == "gmm"
+    assert args["visits"] == max_tile_visits(4096, 16, cfg.bm)
+    assert args["weight_per_group"] == (cfg.bk >= 512)
 
 
 # --------------------------------------------------------------------- #

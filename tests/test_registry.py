@@ -417,6 +417,53 @@ def test_tpu_block_records_of_an_older_model_are_not_served(store):
     assert store.get(fp).evals > 0          # tuned here, not served
 
 
+def test_gmm_model_version_moves_gmm_digests_not_matmul_digests():
+    """Grouped records carry a model version of their own: the grouped
+    model's change re-tunes ``tpu_gmm_block`` records and leaves every
+    ``tpu_block`` record valid."""
+    import dataclasses
+    from repro.registry import gmm_block_fingerprint
+    from repro.registry.fingerprint import (FINGERPRINT_VERSION,
+                                            TPU_BLOCK_MODEL_VERSION, _digest)
+    # the digest of (4096, 18432, 4608) bf16 under matmul model 2
+    assert matmul_block_fingerprint(4096, 18432, 4608, 2, TPU_V5E).digest \
+        == "551484dcd51e19c25790caca03abb613482e6330c13b779a6f7755541fe30cf8"
+    shared = {"kind": "tpu_gmm_block", "version": FINGERPRINT_VERSION,
+              "model": TPU_BLOCK_MODEL_VERSION, "dtype_bytes": 2,
+              "hw": dataclasses.asdict(TPU_V5E)}
+    fp = gmm_block_fingerprint(32768, 896, 2304, 64, 2, TPU_V5E)
+    assert fp.family != _digest(shared)
+    assert fp.digest != _digest(dict(shared, dims=[32768, 896, 2304, 64]))
+
+
+def test_gmm_records_of_the_shared_model_version_are_not_served(store):
+    """A ``tpu_gmm_block`` record tuned when grouped records shared the
+    matmul's model version is neither served nor a warm-start seed."""
+    import dataclasses
+    from repro.kernels.autotune import reset_config_lru, resolve_gmm_config
+    from repro.registry import gmm_block_fingerprint
+    from repro.registry.fingerprint import (FINGERPRINT_VERSION,
+                                            TPU_BLOCK_MODEL_VERSION, _digest)
+    old = {"kind": "tpu_gmm_block", "version": FINGERPRINT_VERSION,
+           "model": TPU_BLOCK_MODEL_VERSION, "dtype_bytes": 2,
+           "hw": dataclasses.asdict(TPU_V5E)}
+    stale = {"bm": 424, "bk": 256, "bn": 256, "k_innermost": True,
+             "latency_s": 1e-9, "feasible": True}
+    store.put(Record(
+        fingerprint=_digest(dict(old, dims=[512, 256, 256, 8])),
+        family=_digest(old), features=[9.0, 8.0, 8.0, 3.0],
+        workload="gmm_old", kind="tpu_gmm_block", hardware=TPU_V5E.name,
+        best=stale, pareto=[], evals=1))
+    fp = gmm_block_fingerprint(512, 256, 256, 8, 2, TPU_V5E)
+    assert store.get(fp) is None
+    assert store.neighbors(fp, k=2) == []
+
+    reset_config_lru()
+    cfg = resolve_gmm_config(512, 256, 256, 8, registry=store, evals=300)
+    assert store.get(fp).evals > 0          # tuned here, not served
+    assert (cfg.bm, cfg.bk, cfg.bn) != (424, 256, 256)
+
+
 # ------------------------------------------------------------------ #
 # CLI
 # ------------------------------------------------------------------ #
