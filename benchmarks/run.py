@@ -39,7 +39,6 @@ def main() -> None:
     benches = [
         ("search_speed", "search_speed:bench_search_speed"),
         ("registry_warmstart", "registry_warmstart:bench_registry_warmstart"),
-        ("serving_throughput", "serving_throughput:bench_serving_throughput"),
         ("network_dse", "network_dse:bench_network_dse"),
         ("obs_trace", "trace_demo:bench_obs_trace"),
         ("calibration", "calibration:bench_calibration"),

@@ -116,8 +116,8 @@ def network_demo(store: RegistryStore) -> None:
     Every GEMM a served transformer issues (attention projections, MLP,
     LM head; prefill and decode token dims) is extracted into a
     LayerGraph, deduped into shape classes, and swept through the
-    registry-backed engine.  The second run — and any serving replica
-    pointing at the same registry — resolves every class with 0 evals.
+    registry-backed engine.  The second run — and any process pointing
+    at the same registry — resolves every class with 0 evals.
     """
     from repro.configs import get_smoke_config
     from repro.kernels.autotune import pretune_model_config
@@ -143,9 +143,10 @@ def network_demo(store: RegistryStore) -> None:
               f"{rep.per_layer_cycles / rep.uniform_cycles:.0%} of the "
               f"per-layer ideal")
 
-    # the TPU-side twin: pre-resolve every Pallas matmul block config the
-    # serving engine will need (launch/serve.py --pretune does this); the
-    # second pass is what every later replica on this registry pays
+    # the TPU-side twin: pre-resolve the Pallas matmul block config of
+    # every GEMM the model issues (python -m repro.network --pretune does
+    # this); the second pass is what every later process on this
+    # registry pays
     from repro.kernels.autotune import reset_config_lru
     for attempt in ("first replica", "later replicas"):
         stats = pretune_model_config(cfg, batch=4, prefill_len=64,
@@ -157,20 +158,19 @@ def network_demo(store: RegistryStore) -> None:
 
 
 def serving_demo() -> None:
-    """Continuous batching vs the wave barrier (DESIGN.md §10).
+    """Continuous batching (DESIGN.md §10).
 
     A mixed stream — mostly short EOS-terminated replies plus a long
-    tail — through both schedulers.  The wave engine makes every request
-    wait for the slowest in its admission wave; the continuous engine
-    recycles each decode slot at EOS, so the same requests finish in far
-    fewer decode steps.  (The deterministic forced-EOS stub model keeps
-    this instant; swap in `build_model(get_smoke_config(...))` and real
-    prompts for an actual LM — the engines are model-agnostic.)
+    tail — through 4 decode slots.  Each slot is recycled at its
+    request's EOS, so no request waits for the slowest of a batch.
+    (The deterministic forced-EOS stub model keeps this instant; swap in
+    `build_model(get_smoke_config(...))` and real prompts for an actual
+    LM — the engine is model-agnostic.)
     """
-    from repro.serve import ServeConfig, make_engine
+    from repro.serve import ContinuousServingEngine, ServeConfig
     from repro.serve.sim import countdown_model, poisson_requests
 
-    print("\nserving: continuous batching vs wave barrier "
+    print("\nserving: continuous batching "
           "(mixed EOS-terminated lengths, 4 slots)")
     model = countdown_model(vocab_size=64)
     params = model.init(None)
@@ -178,10 +178,8 @@ def serving_demo() -> None:
                       prefill_chunk=16)
     requests = poisson_requests(16, rate_rps=0, vocab_size=64,
                                 max_new_tokens=64, seed=0)
-    for scheduler in ("wave", "continuous"):
-        eng = make_engine(scheduler, model, params, cfg)
-        _, stats = eng.serve([r for r in requests])
-        print(f"  {stats.summary()}")
+    _, stats = ContinuousServingEngine(model, params, cfg).serve(requests)
+    print(f"  {stats.summary()}")
 
 
 def tracing_demo() -> None:

@@ -21,7 +21,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.data import DataConfig, SyntheticLM
 from repro.models import build_model
-from repro.serve import ServeConfig, ServingEngine
+from repro.serve import ContinuousServingEngine, ServeConfig
 from repro.train import AdamWConfig, build_train_step, create_train_state
 
 ap = argparse.ArgumentParser()
@@ -61,7 +61,8 @@ for i in range(args.steps):
               flush=True)
 
 print(f"\ntrained {args.steps} steps in {time.time() - t0:.1f}s")
-eng = ServingEngine(model, state["params"], ServeConfig(max_batch=4))
+eng = ContinuousServingEngine(model, state["params"],
+                              ServeConfig(max_batch=4))
 prompt = data.batch(9999)["tokens"][0, :8].astype(np.int32)
 out = eng.generate([prompt], max_new_tokens=8)[0]
 expect = [(data.a * t + data.b) % cfg.vocab_size for t in

@@ -21,7 +21,6 @@ from __future__ import annotations
 import collections
 import dataclasses
 import functools
-import math
 import os
 import random
 import threading
@@ -54,6 +53,18 @@ def _up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 
 
+def _cdiv(x: int, m: int) -> int:
+    return -(-x // m)
+
+
+def _where(cond, a, b):
+    """``np.where`` that keeps Python scalars scalar: the search prices
+    one genome at a time at least as often as a population."""
+    if isinstance(cond, np.ndarray):
+        return np.where(cond, a, b)
+    return a if cond else b
+
+
 @dataclasses.dataclass
 class TpuMatmulModel:
     """Analytic latency/VMEM model of the Pallas matmul on one TPU core."""
@@ -64,15 +75,63 @@ class TpuMatmulModel:
     dtype_bytes: int = 2
     hw: HardwareProfile = TPU_V5E
 
-    def grid(self, g: BlockGenome) -> Tuple[int, int, int]:
-        bm, bk, bn, _ = g
-        return (math.ceil(self.M / bm), math.ceil(self.N / bn),
-                math.ceil(self.K / bk))
+    # Each cost term is written once, over Python ints or NumPy integer
+    # arrays of the block dims and bools (or bool arrays) of k_inner:
+    # the scalar methods and ``fitness_batch`` run the same arithmetic,
+    # so a genome is priced bit for bit alike either way.
+    def _grid(self, bm, bk, bn):
+        return _cdiv(self.M, bm), _cdiv(self.N, bn), _cdiv(self.K, bk)
 
-    def vmem_bytes(self, g: BlockGenome) -> int:
-        bm, bk, bn, k_inner = g
+    def _compute_s(self, bm, bk, bn):
+        # MXU granularity: sublane 8 on M, lane 128 on K/N
+        flops = 2 * _up(bm, 8) * _up(bk, 128) * _up(bn, 128)
+        return flops / self.hw.flops_peak
+
+    def _dma_s(self, bm, bk, bn, k_inner):
+        gk = self._grid(bm, bk, bn)[2]
+        bytes_in = (bm * bk + bk * bn) * self.dtype_bytes
+        # C written once per (m, n) block, amortized over the k sweep; the
+        # k-outer partial sums' round trip is waited (``step_cost_s``)
+        bytes_out = _where(k_inner, bm * bn * self.dtype_bytes / gk, 0.0)
+        t = (bytes_in + bytes_out) / self.hw.hbm_bw
+        return t + self.hw.dma_overhead_cycles / self.hw.freq_hz
+
+    def _pipeline_s(self, bm, bk, bn, k_inner):
+        gm, gn, gk = self._grid(bm, bk, bn)
+        tc = self._compute_s(bm, bk, bn)
+        td = self._dma_s(bm, bk, bn, k_inner)
+        prologue, steady = td, _where(tc > td, tc, td)
+        epilogue = (bm * bn * self.dtype_bytes) / self.hw.hbm_bw
+        return prologue + tc + (gm * gn * gk - 1) * steady + epilogue
+
+    def _step_cost_s(self, bm, bk, bn, k_inner):
+        gm, gn, gk = self._grid(bm, bk, bn)
+        acc = bm * bn * 4
+        extra = _where(k_inner,
+                       gm * gn * (gk - 1) * acc * ACC_RMW_S_PER_BYTE,
+                       gm * gn * gk * (2 * acc / self.hw.hbm_bw))
+        return gm * gn * gk * GRID_STEP_S + extra
+
+    def _latency_s(self, bm, bk, bn, k_inner):
+        return self._pipeline_s(bm, bk, bn, k_inner) \
+            + self._step_cost_s(bm, bk, bn, k_inner)
+
+    def _vmem_bytes(self, bm, bk, bn, k_inner):
         return vmem_bytes(bm, bk, bn, self.dtype_bytes, k_inner,
                           masked=self.K % bk != 0)
+
+    def _fitness(self, bm, bk, bn, k_inner):
+        lat = self._latency_s(bm, bk, bn, k_inner)
+        v = vmem_limit_bytes(self._vmem_bytes(bm, bk, bn, k_inner))
+        return -_where(v > VMEM_LIMIT_MAX,
+                       lat * _quartic(v / VMEM_LIMIT_MAX), lat)
+
+    def grid(self, g: BlockGenome) -> Tuple[int, int, int]:
+        gm, gn, gk = self._grid(*g[:3])
+        return int(gm), int(gn), int(gk)
+
+    def vmem_bytes(self, g: BlockGenome) -> int:
+        return self._vmem_bytes(*g)
 
     def vmem_limit_bytes(self, g: BlockGenome) -> int:
         """The scoped-VMEM limit ``matmul`` passes to Mosaic for ``g``."""
@@ -82,100 +141,39 @@ class TpuMatmulModel:
         return self.vmem_limit_bytes(g) <= VMEM_LIMIT_MAX
 
     def block_compute_s(self, g: BlockGenome) -> float:
-        bm, bk, bn, _ = g
-        # MXU granularity: sublane 8 on M, lane 128 on K/N
-        flops = 2 * _up(bm, 8) * _up(bk, 128) * _up(bn, 128)
-        return flops / self.hw.flops_peak
+        return float(self._compute_s(*g[:3]))
 
     def block_dma_s(self, g: BlockGenome) -> float:
-        bm, bk, bn, k_inner = g
-        gm, gn, gk = self.grid(g)
-        bytes_in = (bm * bk + bk * bn) * self.dtype_bytes
-        # C written once per (m, n) block, amortized over the k sweep; the
-        # k-outer partial sums' round trip is waited (``step_cost_s``)
-        bytes_out = bm * bn * self.dtype_bytes / gk if k_inner else 0
-        t = (bytes_in + bytes_out) / self.hw.hbm_bw
-        return t + self.hw.dma_overhead_cycles / self.hw.freq_hz
+        return float(self._dma_s(*g))
 
     def pipeline_s(self, g: BlockGenome) -> float:
         """The paper's double-buffered pipeline: prologue + steady-state
         max(compute, DMA) per block + epilogue."""
-        gm, gn, gk = self.grid(g)
-        n_blocks = gm * gn * gk
-        tc, td = self.block_compute_s(g), self.block_dma_s(g)
-        prologue = td
-        epilogue = (g[0] * g[2] * self.dtype_bytes) / self.hw.hbm_bw
-        return prologue + tc + (n_blocks - 1) * max(tc, td) + epilogue
+        return float(self._pipeline_s(*g))
 
     def step_cost_s(self, g: BlockGenome) -> float:
         """What the grid steps cost beyond the pipeline's max(compute,
         DMA): a fixed cost per step, and the f32 accumulator's update,
         in VMEM on each k-inner step after the first, or as the k-outer
         partial sum's waited round trip through HBM on every step."""
-        bm, _, bn, k_inner = g
-        gm, gn, gk = self.grid(g)
-        acc = bm * bn * 4
-        if k_inner:
-            extra = gm * gn * (gk - 1) * acc * ACC_RMW_S_PER_BYTE
-        else:
-            extra = gm * gn * gk * (2 * acc / self.hw.hbm_bw)
-        return gm * gn * gk * GRID_STEP_S + extra
+        return float(self._step_cost_s(*g))
 
     def latency_s(self, g: BlockGenome) -> float:
-        return self.pipeline_s(g) + self.step_cost_s(g)
+        return float(self._latency_s(*g))
 
     def fitness(self, g: BlockGenome) -> float:
-        lat = self.latency_s(g)
-        v = self.vmem_limit_bytes(g)
-        if v > VMEM_LIMIT_MAX:
-            lat *= _quartic(v / VMEM_LIMIT_MAX)
-        return -lat
+        """Minus the latency, times the quartic penalty of a VMEM limit
+        over ``VMEM_LIMIT_MAX``."""
+        return float(self._fitness(*g))
 
     def mfu(self, g: BlockGenome) -> float:
         useful = 2 * self.M * self.N * self.K
         return useful / self.hw.flops_peak / self.latency_s(g)
 
-    # -- batched evaluation (same interface as BatchPerformanceModel) ------
     def fitness_batch(self, genomes: Sequence[BlockGenome]) -> np.ndarray:
-        """Vectorized ``fitness`` over a whole population.
-
-        Mirrors the scalar arithmetic operation-for-operation (same float
-        divisions and accumulation order), so it matches scalar ``fitness``
-        bit-for-bit — the same contract the FPGA-side batch model honors.
-        """
-        bm = np.array([g[0] for g in genomes], dtype=np.int64)
-        bk = np.array([g[1] for g in genomes], dtype=np.int64)
-        bn = np.array([g[2] for g in genomes], dtype=np.int64)
-        k_inner = np.array([g[3] for g in genomes], dtype=bool)
-        db = self.dtype_bytes
-
-        gm = np.ceil(self.M / bm)
-        gn = np.ceil(self.N / bn)
-        gk = np.ceil(self.K / bk)
-
-        def up(x, m):
-            return ((x + m - 1) // m) * m
-
-        tc = (2 * up(bm, 8) * up(bk, 128) * up(bn, 128)) / self.hw.flops_peak
-        bytes_in = (bm * bk + bk * bn) * db
-        bytes_out = np.where(k_inner, bm * bn * db / gk, 0.0)
-        td = (bytes_in + bytes_out) / self.hw.hbm_bw \
-            + self.hw.dma_overhead_cycles / self.hw.freq_hz
-
-        n_blocks = gm * gn * gk
-        epilogue = (bm * bn * db) / self.hw.hbm_bw
-        acc = bm * bn * 4
-        extra = np.where(k_inner,
-                         gm * gn * (gk - 1) * acc * ACC_RMW_S_PER_BYTE,
-                         n_blocks * (2 * acc / self.hw.hbm_bw))
-        lat = (td + tc + (n_blocks - 1) * np.maximum(tc, td) + epilogue) \
-            + (n_blocks * GRID_STEP_S + extra)
-
-        limit = vmem_limit_bytes(vmem_bytes(bm, bk, bn, db, k_inner,
-                                            masked=self.K % bk != 0))
-        lat = np.where(limit > VMEM_LIMIT_MAX,
-                       lat * _quartic(limit / VMEM_LIMIT_MAX), lat)
-        return -lat
+        """``fitness`` over a whole population at once (the interface of
+        ``BatchPerformanceModel``)."""
+        return self._fitness(*(np.array(c) for c in zip(*genomes)))
 
 
 class TpuMatmulProblem(Problem):
@@ -617,9 +615,9 @@ def pretune_model_config(mcfg, batch: int, prefill_len: int,
     """One network pass over a model config's whole GEMM graph.
 
     Builds the per-layer prefill+decode :class:`repro.network.LayerGraph`
-    for ``mcfg`` and resolves every unique (M, N, K) block config, so a
-    serving replica (``launch/serve.py --pretune``) starts with all of
-    its matmul schedules decided before traffic arrives.
+    for ``mcfg`` and resolves every unique (M, N, K) block config, as
+    ``python -m repro.network --pretune`` does, so a later process
+    against the same registry finds every matmul schedule decided.
     """
     from repro.network.graph import model_config_graph
     graph = model_config_graph(mcfg, batch=batch, prefill_len=prefill_len,

@@ -2,8 +2,7 @@
 
 ``python -m repro.launch.serve --arch smollm-135m --smoke --requests 8``
 
-``--scheduler continuous`` (default) admits requests into free decode slots
-mid-stream; ``--scheduler wave`` is the wave-synchronous baseline.
+The continuous engine admits requests into free decode slots mid-stream.
 ``--poisson-rate R`` replays a Poisson arrival trace at R requests/sec
 instead of queueing everything at t=0.
 """
@@ -11,7 +10,6 @@ instead of queueing everything at t=0.
 from __future__ import annotations
 
 import argparse
-import os
 
 import jax
 
@@ -19,7 +17,7 @@ from repro.ckpt import latest_checkpoint, restore_params
 from repro.configs import ARCH_IDS, get_config, get_smoke_config
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
-from repro.serve import SCHEDULERS, ServeConfig, make_engine
+from repro.serve import ContinuousServingEngine, ServeConfig
 from repro.serve.sim import poisson_requests
 
 
@@ -32,8 +30,6 @@ def main(argv=None):
     ap.add_argument("--max-new-tokens", type=int, default=16)
     ap.add_argument("--max-batch", type=int, default=4)
     ap.add_argument("--max-seq", type=int, default=256)
-    ap.add_argument("--scheduler", default="continuous",
-                    choices=sorted(SCHEDULERS))
     ap.add_argument("--eos-token", type=int, default=None,
                     help="stop decoding at this token id (default: decode "
                          "the full budget)")
@@ -44,15 +40,6 @@ def main(argv=None):
     ap.add_argument("--trace", default=None, metavar="PATH",
                     help="stream spans/counters to this .trace.jsonl "
                          "(render with python -m repro.obs to-perfetto)")
-    ap.add_argument("--registry-dir", default=None,
-                    help="shared design-registry root; replicas pointing at "
-                         "the same dir share tuned kernels (default: "
-                         "$REPRO_REGISTRY_DIR if set, else disabled)")
-    ap.add_argument("--pretune", action="store_true",
-                    help="resolve every GEMM block config of the model's "
-                         "layer graph (prefill + decode) through the "
-                         "registry before serving; a replica against a "
-                         "warm registry resolves all of them with 0 evals")
     args = ap.parse_args(argv)
     enable_compile_cache()
 
@@ -69,36 +56,10 @@ def main(argv=None):
             params = restore_params(path, params)
             print(f"[serve] restored {path}")
 
-    tuning = None
-    from repro.registry import DEFAULT_ROOT_ENV
-    registry_dir = args.registry_dir or os.environ.get(DEFAULT_ROOT_ENV)
-    if registry_dir:
-        from repro.registry import RegistryStore, TuningService
-        tuning = TuningService(RegistryStore(registry_dir))
-
-    if args.pretune:
-        from repro.kernels.autotune import pretune_model_config
-        stats = pretune_model_config(
-            cfg, batch=args.max_batch, prefill_len=args.max_seq,
-            registry=tuning.store if tuning is not None else None)
-        print(f"[serve] pretune: {stats['shapes']} layer GEMM shapes — "
-              f"{stats['tuned']} tuned, {stats['disk_hits']} from "
-              f"registry, {stats['lru_hits']} from LRU, "
-              f"{stats['evals']} search evals")
-        if tuning is None:
-            print("[serve] pretune warning: no --registry-dir, configs "
-                  "live only in this process's LRU")
-
-    eng = make_engine(args.scheduler, model, params,
-                      ServeConfig(max_batch=args.max_batch,
-                                  max_seq=args.max_seq,
-                                  eos_token=args.eos_token),
-                      tuning=tuning)
-    if tuning is not None:
-        print(f"[serve] registry {registry_dir}: resolved "
-              f"{len(eng.kernel_configs)} GEMM block shapes "
-              f"({eng.kernel_stats['shared']} shared from other replicas, "
-              f"{eng.kernel_stats['tuned']} tuned here)")
+    eng = ContinuousServingEngine(model, params,
+                                  ServeConfig(max_batch=args.max_batch,
+                                              max_seq=args.max_seq,
+                                              eos_token=args.eos_token))
 
     requests = poisson_requests(args.requests, rate_rps=args.poisson_rate,
                                 vocab_size=cfg.vocab_size,

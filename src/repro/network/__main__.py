@@ -5,9 +5,9 @@ CONV networks (systolic-array DSE over the whole layer graph):
     python -m repro.network --model vgg16 --k 1 2 4 --json out.json
     python -m repro.network --model resnet50 --registry-dir /tmp/reg
 
-Model configs (GEMM graph; ``--pretune`` resolves every Pallas block
-config the served model will issue through the shared registry — the
-serving warm-start pass, see ``launch/serve.py --pretune``):
+Model configs (GEMM graph; ``--pretune`` resolves the Pallas block config
+of every GEMM the model issues through the shared registry, so a later
+process against the same registry resolves them with 0 evals):
 
     python -m repro.network --model smollm-135m --smoke --batch 4 \
         --prefill 256 --pretune --registry-dir /tmp/reg

@@ -1,9 +1,9 @@
 """Continuous batching: slot-based scheduling over a fixed-capacity KV cache.
 
-The wave engine's barrier (every request waits for the slowest in its wave)
-is the serving analog of the pruned design spaces the Odyssey paper
-quantifies: convenient, but it idles compute slots on synchronization.
-This engine removes it (DESIGN.md §10):
+The serving scheduler (DESIGN.md §10).  A wave barrier (every request
+waits for the slowest in its batch) would be the serving analog of the
+pruned design spaces the Odyssey paper quantifies: convenient, but it
+idles compute slots on synchronization.  This engine has none:
 
   * ``max_batch`` **decode slots** back a single batched cache of capacity
     ``max_seq`` per slot; a request occupies one slot from admission to its
@@ -70,9 +70,8 @@ class ContinuousServingEngine(EngineBase):
 
     scheduler = "continuous"
 
-    def __init__(self, model, params, cfg, tuning=None, tune_evals: int = 800):
-        super().__init__(model, params, cfg, tuning=tuning,
-                         tune_evals=tune_evals)
+    def __init__(self, model, params, cfg):
+        super().__init__(model, params, cfg)
         self._cache_dtype = jnp.float32 \
             if getattr(model.cfg, "dtype", "bfloat16") == "float32" \
             else jnp.bfloat16

@@ -6,10 +6,8 @@ generates ``t0+1, t0+2, ..., V-1, 0`` — so with ``eos_token=0`` the output
 length is exactly ``V - t0``, deterministically heterogeneous across
 prompts.  It honors the full decode-step cache contract (chunked prefill,
 ``kv_start``, parked slots) while costing almost nothing per step, which
-makes it the scheduler-isolation workload for
-``benchmarks/serving_throughput.py`` and the EOS regression tests: both
-engines run the identical model, so any throughput difference is pure
-scheduling.
+makes it the scheduler-isolation workload of the EOS and overload
+regression tests and of ``benchmarks/trace_demo.py``.
 """
 
 from __future__ import annotations

@@ -1,10 +1,10 @@
-"""Serving request/metrics types shared by the wave and continuous engines.
+"""Serving request/metrics types of the continuous engine.
 
 A :class:`Request` is what a client submits (prompt + decode budget +
 arrival time for trace replay); a :class:`RequestMetrics` is what the
 scheduler measured for it; a :class:`ServeStats` aggregates one serving run
-into the report `launch/serve.py` prints and
-`benchmarks/serving_throughput.py` writes as a JSON artifact.
+into the report `launch/serve.py` prints and the benchmark's serving
+window reads.
 """
 
 from __future__ import annotations
@@ -41,7 +41,7 @@ class RequestMetrics:
     request_id: int
     prompt_len: int
     new_tokens: int
-    queue_wait_s: float        # arrival -> admitted into a slot/wave
+    queue_wait_s: float        # arrival -> admitted into a slot
     ttft_s: float              # arrival -> first generated token
     decode_s: float            # first generated token -> last
     finish_reason: str         # "eos" | "length" | "timeout" | "shed"

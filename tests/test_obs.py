@@ -367,11 +367,12 @@ def test_serve_stats_provenance_and_rolling():
                            queue_wait_s=0.01, ttft_s=0.02 * (i + 1),
                            decode_s=0.07, finish_reason="length")
             for i in range(5)]
-    stats = ServeStats(scheduler="wave", requests=reqs, wall_s=1.0,
-                       engine="ServingEngine")
+    stats = ServeStats(scheduler="continuous", requests=reqs, wall_s=1.0,
+                       engine="ContinuousServingEngine")
     d = stats.to_dict()
-    assert d["engine"] == "ServingEngine"
-    assert all(r["scheduler"] == "wave" and r["engine"] == "ServingEngine"
+    assert d["engine"] == "ContinuousServingEngine"
+    assert all(r["scheduler"] == "continuous"
+               and r["engine"] == "ContinuousServingEngine"
                for r in d["per_request"])
     roll = stats.rolling(window=3)             # only the last 3 retained
     assert roll["ttft_s"]["count"] == 5

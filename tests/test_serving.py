@@ -1,5 +1,5 @@
-"""Serving engines: prefill+decode equals teacher forcing; EOS stopping;
-ragged left-padded batches; continuous-batching slot recycling."""
+"""The continuous serving engine: chunked prefill + decode equals teacher
+forcing; EOS stopping; ragged batches; slot recycling; overload policy."""
 
 import dataclasses
 
@@ -12,25 +12,33 @@ import numpy as np
 
 from repro.configs import get_smoke_config
 from repro.models import build_model
+from repro.kernels.autotune import tuner_counts
 from repro.serve import (ContinuousServingEngine, Request, ServeConfig,
-                         ServingEngine, greedy_mismatches, make_engine)
+                         greedy_mismatches, make_engine)
 from repro.faults import FaultPlan, FaultSpec, injected
 from repro.serve.sim import (bursty_requests, countdown_model,
                              poisson_requests)
 
 
-def _engine(arch="smollm-135m", scheduler="wave", **cfg_kw):
+def _engine(arch="smollm-135m", **cfg_kw):
     cfg = dataclasses.replace(get_smoke_config(arch), dtype="float32")
     model = build_model(cfg)
     params = model.init(jax.random.key(0))
     cfg_kw.setdefault("max_batch", 4)
     cfg_kw.setdefault("max_seq", 64)
-    return model, params, make_engine(scheduler, model, params,
-                                      ServeConfig(**cfg_kw))
+    return model, params, ContinuousServingEngine(model, params,
+                                                  ServeConfig(**cfg_kw))
 
 
-def test_greedy_generation_matches_manual_decode():
-    model, params, eng = _engine()
+# the engine's two prefill paths: each prompt of these tests in one chunk,
+# and spread over several chunks
+PREFILL = pytest.mark.parametrize("prefill_chunk", [32, 3],
+                                  ids=["whole", "chunked"])
+
+
+@PREFILL
+def test_greedy_generation_matches_manual_decode(prefill_chunk):
+    model, params, eng = _engine(prefill_chunk=prefill_chunk)
     prompt = np.array([5, 9, 2, 7], np.int32)
     out = eng.generate([prompt], max_new_tokens=6)[0]
     # manual: full forward re-run per step (teacher forcing on own output)
@@ -45,11 +53,10 @@ def test_greedy_generation_matches_manual_decode():
     assert list(out) == manual
 
 
-@pytest.mark.parametrize("scheduler", ["wave", "continuous"])
-def test_served_tokens_match_cache_free_reference(scheduler):
+def test_served_tokens_match_cache_free_reference():
     """The served-model check of ``chip_smoke.py`` at smoke size: every
     request's greedy tokens equal the argmax of one cache-free forward."""
-    model, params, eng = _engine(scheduler=scheduler)
+    model, params, eng = _engine()
     reqs = poisson_requests(8, rate_rps=0.0, vocab_size=model.cfg.vocab_size,
                             prompt_len=range(2, 8), max_new_tokens=16, seed=0)
     with jax.default_matmul_precision("highest"):
@@ -63,9 +70,13 @@ def test_served_tokens_match_cache_free_reference(scheduler):
     assert len(bad) == 1 and bad[0].startswith("request 3: served token 5")
 
 
-def test_generation_batching_waves():
-    model, params, eng = _engine()
-    prompts = [np.array([i + 1, i + 2], np.int32) for i in range(7)]
+@PREFILL
+def test_generation_batching_through_slots(prefill_chunk):
+    """7 prompts through 4 slots: each recycled slot serves a request as
+    if it were served alone."""
+    model, params, eng = _engine(prefill_chunk=prefill_chunk)
+    prompts = [np.array([i + 1, i + 2, i + 3, i + 4], np.int32)
+               for i in range(7)]
     outs = eng.generate(prompts, max_new_tokens=4)
     assert len(outs) == 7
     assert all(len(o) == 4 for o in outs)
@@ -74,32 +85,30 @@ def test_generation_batching_waves():
     np.testing.assert_array_equal(outs[5], solo)
 
 
-@pytest.mark.parametrize("scheduler", ["wave", "continuous"])
-def test_ragged_prompts_match_unbatched(scheduler):
-    """Left-padded short prompts in a batch must produce exactly the greedy
-    tokens of serving each prompt unbatched — every row, not just the
-    longest (positions/caches for rows shorter than plen)."""
-    model, params, eng = _engine(scheduler=scheduler, prefill_chunk=4)
+def test_ragged_prompts_match_unbatched():
+    """Short prompts in a batch must produce exactly the greedy tokens of
+    serving each prompt unbatched — every row, not just the longest
+    (positions/caches for rows shorter than the longest)."""
+    model, params, eng = _engine(prefill_chunk=4)
     prompts = [np.array([3], np.int32),
                np.array([4, 5, 6], np.int32),
                np.array([9, 1, 9, 1, 9, 1, 9], np.int32)]
     outs = eng.generate(prompts, max_new_tokens=5)
-    _, _, solo_eng = _engine()  # fresh wave engine, one request at a time
+    _, _, solo_eng = _engine(max_batch=1)  # one request at a time
     for i, p in enumerate(prompts):
         solo = solo_eng.generate([p], max_new_tokens=5)[0]
-        np.testing.assert_array_equal(
-            outs[i], solo, err_msg=f"{scheduler}: row {i} diverged")
+        np.testing.assert_array_equal(outs[i], solo,
+                                      err_msg=f"row {i} diverged")
 
 
-@pytest.mark.parametrize("scheduler", ["wave", "continuous"])
-def test_eos_stops_and_truncates(scheduler):
+def test_eos_stops_and_truncates():
     """A model forced to emit EOS: generation must stop there and the
     returned sequence must end with EOS (no post-EOS tokens)."""
     model = countdown_model(vocab_size=16)
     params = model.init(None)
-    eng = make_engine(scheduler, model, params,
-                      ServeConfig(max_batch=2, max_seq=64, eos_token=0,
-                                  prefill_chunk=4))
+    eng = ContinuousServingEngine(model, params,
+                                  ServeConfig(max_batch=2, max_seq=64,
+                                              eos_token=0, prefill_chunk=4))
     prompts = [np.array([12], np.int32),          # -> 13,14,15,0
                np.array([5, 9], np.int32),        # -> 10..15,0
                np.array([14, 14, 15], np.int32)]  # -> 0 (EOS immediately)
@@ -110,9 +119,10 @@ def test_eos_stops_and_truncates(scheduler):
         [13, 14, 15, 0], [10, 11, 12, 13, 14, 15, 0], [0]]
     assert all(m.finish_reason == "eos" for m in stats.requests)
     # without EOS the same model decodes the full budget
-    eng2 = make_engine(scheduler, model, params,
-                       ServeConfig(max_batch=2, max_seq=64, eos_token=None,
-                                   prefill_chunk=4))
+    eng2 = ContinuousServingEngine(model, params,
+                                   ServeConfig(max_batch=2, max_seq=64,
+                                               eos_token=None,
+                                               prefill_chunk=4))
     outs2, _ = eng2.serve([Request(prompt=prompts[0], max_new_tokens=8,
                                    request_id=0)])
     assert len(outs2[0]) == 8
@@ -146,35 +156,23 @@ def test_continuous_recycles_slots_and_reports_stats():
 
 def test_continuous_chunked_prefill_crosses_chunks():
     """Prompts longer than prefill_chunk must prefill over multiple chunks
-    and still match the unbatched wave decode."""
-    model, params, eng = _engine(scheduler="continuous", max_batch=2,
-                                 prefill_chunk=3)
+    and still match a one-slot engine that prefills the prompt whole."""
+    model, params, eng = _engine(max_batch=2, prefill_chunk=3)
     prompt = np.array([7, 3, 9, 1, 4, 8, 2, 6, 5, 1, 2], np.int32)  # 11 > 3
     out = eng.generate([prompt], max_new_tokens=6)[0]
-    _, _, wave = _engine()
-    solo = wave.generate([prompt], max_new_tokens=6)[0]
+    _, _, whole = _engine(max_batch=1)  # prefill_chunk 32 >= 11
+    solo = whole.generate([prompt], max_new_tokens=6)[0]
     np.testing.assert_array_equal(out, solo)
 
 
-def test_continuous_matches_wave_on_common_workload():
-    model, params, weng = _engine()
-    ceng = ContinuousServingEngine(model, params,
-                                   ServeConfig(max_batch=3, max_seq=64,
-                                               prefill_chunk=8))
-    rng = np.random.default_rng(0)
-    prompts = [rng.integers(0, 256, size=rng.integers(1, 9)).astype(np.int32)
-               for _ in range(7)]
-    wouts = weng.generate(prompts, max_new_tokens=4)
-    couts = ceng.generate(prompts, max_new_tokens=4)
-    for w, c in zip(wouts, couts):
-        np.testing.assert_array_equal(w, c)
-
-
-def test_wave_serve_per_request_budgets():
-    """Mixed decode budgets in one wave: each row stops at its own."""
-    model, params, eng = _engine()
-    reqs = [Request(prompt=np.array([2, 3], np.int32), max_new_tokens=n,
-                    request_id=i) for i, n in enumerate([1, 3, 6])]
+@PREFILL
+def test_wave_serve_per_request_budgets(prefill_chunk):
+    """Mixed decode budgets served side by side: each request stops at
+    its own."""
+    model, params, eng = _engine(prefill_chunk=prefill_chunk)
+    reqs = [Request(prompt=np.array([2, 3, 5, 7], np.int32),
+                    max_new_tokens=n, request_id=i)
+            for i, n in enumerate([1, 3, 6])]
     outs, stats = eng.serve(reqs)
     assert [len(o) for o in outs] == [1, 3, 6]
     assert [m.new_tokens for m in stats.requests] == [1, 3, 6]
@@ -183,20 +181,19 @@ def test_wave_serve_per_request_budgets():
 
 
 def test_mamba_serving_still_works():
-    """Non-attention family through both schedulers (whole-prompt chunks,
-    no ragged contract)."""
+    """Non-attention family (exact-length chunks, no ragged contract):
+    served tokens equal the argmax of the model's cache-free forward."""
     cfg = get_smoke_config("mamba2-130m")
     model = build_model(cfg)
     params = model.init(jax.random.key(0))
-    prompts = [np.array([3, 1, 4, 1, 5], np.int32)]
-    w = ServingEngine(model, params,
-                      ServeConfig(max_batch=2, max_seq=48)
-                      ).generate(prompts, max_new_tokens=4)
-    c = ContinuousServingEngine(model, params,
-                                ServeConfig(max_batch=2, max_seq=48,
-                                            prefill_chunk=3)
-                                ).generate(prompts, max_new_tokens=4)
-    np.testing.assert_array_equal(w[0], c[0])
+    reqs = [Request(prompt=np.array([3, 1, 4, 1, 5], np.int32),
+                    max_new_tokens=4)]
+    outs, _ = ContinuousServingEngine(model, params,
+                                      ServeConfig(max_batch=2, max_seq=48,
+                                                  prefill_chunk=3)
+                                      ).serve(reqs)
+    assert len(outs[0]) == 4
+    assert greedy_mismatches(model, params, reqs, outs) == []
 
 
 def test_request_ids_are_labels_not_indices():
@@ -216,25 +213,43 @@ def test_request_ids_are_labels_not_indices():
     assert [m.request_id for m in stats.requests] == [7, 7]
 
 
-@pytest.mark.parametrize("scheduler", ["wave", "continuous"])
-def test_empty_prompt_rejected(scheduler):
+def test_empty_prompt_rejected():
     model = countdown_model(vocab_size=16)
     params = model.init(None)
-    eng = make_engine(scheduler, model, params,
+    eng = make_engine("continuous", model, params,
                       ServeConfig(max_batch=2, max_seq=48))
     with pytest.raises(ValueError, match="empty prompt"):
         eng.serve([Request(prompt=np.array([], np.int32))])
 
 
-@pytest.mark.parametrize("scheduler", ["wave", "continuous"])
-def test_nonpositive_budget_rejected(scheduler):
+def test_nonpositive_budget_rejected():
     model = countdown_model(vocab_size=16)
     params = model.init(None)
-    eng = make_engine(scheduler, model, params,
+    eng = make_engine("continuous", model, params,
                       ServeConfig(max_batch=2, max_seq=48))
     with pytest.raises(ValueError, match="max_new_tokens"):
         eng.serve([Request(prompt=np.array([3], np.int32),
                            max_new_tokens=0)])
+
+
+def test_make_engine_has_one_scheduler():
+    model = countdown_model(vocab_size=16)
+    params = model.init(None)
+    cfg = ServeConfig(max_batch=2, max_seq=48)
+    assert type(make_engine("continuous", model, params, cfg)) \
+        is ContinuousServingEngine
+    with pytest.raises(ValueError, match="wave"):
+        make_engine("wave", model, params, cfg)
+
+
+def test_serving_resolves_no_block_shape():
+    """Served GEMMs lower through XLA's dot: building an engine and serving
+    through it leaves the tuner's counters where they were."""
+    before = tuner_counts()
+    _, _, eng = _engine(max_batch=2)
+    outs = eng.generate([np.array([5, 9, 2], np.int32)], max_new_tokens=3)
+    assert len(outs[0]) == 3
+    assert tuner_counts() == before
 
 
 # ------------------------------------------------------------------ #

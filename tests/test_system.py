@@ -18,7 +18,7 @@ from repro.configs import get_smoke_config
 from repro.data import DataConfig, SyntheticLM
 from repro.models import build_model
 from repro.runtime import RestartPolicy, run_with_restarts
-from repro.serve import ServeConfig, ServingEngine
+from repro.serve import ContinuousServingEngine, ServeConfig
 from repro.train import AdamWConfig, build_train_step, create_train_state
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -62,8 +62,8 @@ def test_train_crash_resume_serve(tmp_path):
     assert restarts == 1
     assert int(final_state["state"]["opt_state"]["step"]) == total_steps
 
-    eng = ServingEngine(model, final_state["state"]["params"],
-                        ServeConfig(max_batch=2))
+    eng = ContinuousServingEngine(model, final_state["state"]["params"],
+                                  ServeConfig(max_batch=2))
     out = eng.generate([np.array([1, 2, 3], np.int32)], max_new_tokens=4)
     assert len(out[0]) == 4
 
@@ -99,14 +99,14 @@ print("sharding-rules-ok")
     assert "sharding-rules-ok" in out.stdout, out.stderr[-2000:]
 
 
-def test_dryrun_single_cell_subprocess():
+def test_dryrun_single_cell_subprocess(tmp_path):
     """Integration: one full dry-run cell (lower+compile on the 512-device
     production mesh) succeeds from a clean process."""
     env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
     out = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun", "--arch",
          "smollm-135m", "--shape", "decode_32k", "--out-dir",
-         os.path.join(REPO, "experiments", "dryrun_test")],
+         str(tmp_path)],
         env=env, capture_output=True, text=True, timeout=560)
     assert "dry-run complete" in out.stdout, \
         out.stdout[-2000:] + out.stderr[-2000:]

@@ -20,8 +20,8 @@ if str(REPO) not in sys.path:       # the benchmark's package, ``bench``
     sys.path.insert(0, str(REPO))
 
 from bench import counters  # noqa: E402
-from repro.kernels.autotune import (reset_config_lru,  # noqa: E402
-                                    resolve_gmm_config,
+from repro.kernels.autotune import (TpuMatmulModel,  # noqa: E402
+                                    reset_config_lru, resolve_gmm_config,
                                     resolve_matmul_config)
 from repro.registry import RegistryStore  # noqa: E402
 
@@ -69,3 +69,35 @@ def test_starcoder2_dense_picks_unchanged(tmp_path, registry):
                        if registry else None)
     picks = _picks(tmp_path if registry else None)
     assert picks == (FRESH_REGISTRY if registry else NO_REGISTRY)
+
+
+# (M, N, K) -> float.hex of TpuMatmulModel.latency_s of each NO_REGISTRY
+# pick and of its k-outer twin; every one of them fits VMEM, so fitness
+# is minus the latency.  Recorded when the scalar methods and
+# fitness_batch each carried their own copy of the cost terms.
+LATENCY_HEX = {
+    (4096, 4608, 4608): ("0x1.de20c9d39d2b3p-11", "0x1.7e1bd62ee4adfp-10"),
+    (4096, 512, 4608): ("0x1.cb5ee2bde0c07p-14", "0x1.657425aec1d0cp-13"),
+    (4096, 18432, 4608): ("0x1.d4a8731d71ce4p-9", "0x1.1a7dca86f1451p-8"),
+    (4096, 4608, 18432): ("0x1.d5ac421635689p-9", "0x1.0e9e5c160460cp-8"),
+    (4096, 49152, 4608): ("0x1.36b533e45f975p-7", "0x1.7705af3c53727p-7"),
+    (16, 4608, 4608): ("0x1.d27d257ba66edp-15", "0x1.dcf487d15db9bp-15"),
+    (16, 512, 4608): ("0x1.dcc84a1a6685ep-18", "0x1.e0cf55558351fp-18"),
+    (16, 18432, 4608): ("0x1.c7b8c50fe4e92p-13", "0x1.cc40b1b2654ecp-13"),
+    (16, 4608, 18432): ("0x1.c380813480c00p-13", "0x1.cc0dcf7d2577fp-13"),
+    (16, 49152, 4608): ("0x1.2a7797f5c2aefp-11", "0x1.2d7ce0621847fp-11"),
+}
+
+
+def test_pinned_picks_price_bit_for_bit():
+    """The model prices the pinned picks exactly as it did when they were
+    recorded, through the scalar methods and through ``fitness_batch``."""
+    for (M, N, K), pick in NO_REGISTRY.items():
+        model = TpuMatmulModel(M=M, N=N, K=K)
+        twins = (pick, pick[:3] + (False,))
+        batch = model.fitness_batch(twins)
+        for g, want, fb in zip(twins, LATENCY_HEX[(M, N, K)], batch):
+            lat, fit = model.latency_s(g), model.fitness(g)
+            assert type(lat) is float and type(fit) is float
+            assert (lat.hex(), fit.hex(), float(fb).hex()) == \
+                (want, "-" + want, "-" + want), (M, N, K, g)
